@@ -59,6 +59,10 @@ def main() -> None:
     )
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from . import (
         bench_cost_model,
         bench_dataflow,

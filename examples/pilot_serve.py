@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import Checkpointer
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import FUNCTIONS, Session, make_tpu_fleet_topology
 from repro.models import build_model
@@ -27,6 +28,7 @@ from repro.serving import DecodeEngine, params_from_input
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = get_config("gemma3-1b-smoke")  # reduced same-family config
     api = build_model(cfg)
     topo, _ = make_tpu_fleet_topology(pods=2, hosts_per_pod=2)

@@ -19,6 +19,7 @@ import argparse
 import dataclasses
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.base import reduced
 from repro.core import Session, make_tpu_fleet_topology
@@ -50,6 +51,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=sorted(PRESETS), default="demo")
     args = ap.parse_args()
+    enable_compile_cache()
     preset = PRESETS[args.preset]
 
     cfg = reduced(get_config("h2o-danube-1.8b"), **preset["model"])

@@ -7,6 +7,7 @@ from .checkpointer import (
     encode_array,
     flatten_tree,
     load_checkpoint_du,
+    tree_digest,
     unflatten_tree,
 )
 
@@ -19,5 +20,6 @@ __all__ = [
     "encode_array",
     "flatten_tree",
     "load_checkpoint_du",
+    "tree_digest",
     "unflatten_tree",
 ]

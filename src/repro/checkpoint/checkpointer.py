@@ -32,10 +32,12 @@ from __future__ import annotations
 
 import io
 import json
+import zlib
 from concurrent.futures import Future, ThreadPoolExecutor
 from concurrent.futures import wait as futures_wait
 from typing import Any, Dict, List, Optional, Tuple
 
+import ml_dtypes  # noqa: F401 — registers bfloat16/float8 dtype names
 import numpy as np
 
 from ..core import DataUnit, DataUnitDescription, DUState, RuntimeContext
@@ -69,14 +71,43 @@ def unflatten_tree(items: Dict[str, Any]) -> Any:
     return root
 
 
+#: Leading line of a leaf whose dtype the .npy header cannot name — the
+#: ml_dtypes family (bfloat16, float8_*, int4), which .npy would store as
+#: raw void.  The line carries the dtype name; the .npy after it holds the
+#: same bits viewed as unsigned integers of the same width.
+_DTYPE_LINE = b"\x93REPRO-DTYPE "
+
+
 def encode_array(arr) -> bytes:
+    a = np.asarray(arr)
     buf = io.BytesIO()
-    np.save(buf, np.asarray(arr), allow_pickle=False)
+    if a.dtype.isbuiltin == 2:  # a dtype registered by ml_dtypes
+        buf.write(_DTYPE_LINE + a.dtype.name.encode() + b"\n")
+        a = a.view(np.dtype(f"u{a.dtype.itemsize}"))
+    np.save(buf, a, allow_pickle=False)
     return buf.getvalue()
 
 
 def decode_array(data: bytes) -> np.ndarray:
-    return np.load(io.BytesIO(data), allow_pickle=False)
+    """Inverse of :func:`encode_array`, bit for bit, dtype included."""
+    buf = io.BytesIO(data)
+    if not data.startswith(_DTYPE_LINE):
+        return np.load(buf, allow_pickle=False)
+    line = buf.readline()
+    name = line[len(_DTYPE_LINE) : -1].decode()
+    return np.load(buf, allow_pickle=False).view(np.dtype(name))
+
+
+def tree_digest(tree: Any) -> int:
+    """CRC32 over every leaf's path, dtype, shape and bytes — equal digests
+    mean the two trees match bit for bit (used to check that a restored
+    checkpoint is the state that was saved)."""
+    crc = 0
+    for path, leaf in flatten_tree(tree):
+        a = np.ascontiguousarray(np.asarray(leaf))
+        crc = zlib.crc32(f"{path}:{a.dtype.name}:{a.shape}".encode(), crc)
+        crc = zlib.crc32(a.reshape(-1).view(np.uint8), crc)
+    return crc
 
 
 def checkpoint_files(
@@ -135,6 +166,9 @@ class Checkpointer:
         # enabled the ReplicaManager now disperses the DU to its declared
         # replication_factor across failure domains — off this thread.
         du.seal()
+        # the bytes now live in the Pilot-Data: a second, in-process copy
+        # would double the host memory of every checkpoint
+        du.drop_local_buffer()
         self.ctx.store.hset(f"ckpt:{self.run_name}", f"{step:08d}", du.id)
         return du
 

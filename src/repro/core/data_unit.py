@@ -317,18 +317,22 @@ class DataUnit:
             csize = self.description.chunk_size
             self._compute_file_ranges()
             chunks: List[ChunkInfo] = []
-            stream = b"".join(
-                self._files.get(rel, b"") for rel in self._order()
-            )
-            for i in range(0, len(stream), csize):
-                piece = stream[i : i + csize]
-                chunks.append(
-                    ChunkInfo(
-                        index=i // csize,
-                        size=len(piece),
-                        checksum=zlib.crc32(piece),
-                    )
-                )
+            # CRC the stream file by file through views: joining it first
+            # would hold a second copy of the whole DU (gigabytes for a
+            # checkpoint) for the length of the scan
+            crc = size = 0
+            for rel in self._order():
+                view = memoryview(self._files.get(rel, b""))
+                while view:
+                    take = min(csize - size, len(view))
+                    crc = zlib.crc32(view[:take], crc)
+                    size += take
+                    view = view[take:]
+                    if size == csize:
+                        chunks.append(ChunkInfo(len(chunks), size, crc))
+                        crc = size = 0
+            if size:
+                chunks.append(ChunkInfo(len(chunks), size, crc))
             self._chunks = chunks
             self._chunks_dirty = False
             self._store.hset(
@@ -661,6 +665,9 @@ class DataUnit:
             if not self.locations:
                 raise RuntimeError("refusing to drop buffer with no replica")
             self._files = {}
+            # an inline description holds the same bytes
+            if self.description.files:
+                self.description = dataclasses.replace(self.description, files={})
 
     # ----------------------------------------------------------- state mgmt
     def _set_state(self, state: str) -> None:

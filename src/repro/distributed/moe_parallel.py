@@ -15,7 +15,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
 from ..models.moe import moe_mlp_ep, moe_mlp_local
-from .compat import shard_map
 from .context import current
 
 
@@ -49,7 +48,7 @@ def moe_maybe_parallel(moe_params, x, cfg: ModelConfig):
             p, xl, cfg, model_axis=m, reduce_axes=reduce_axes
         )
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=ctx.mesh,
         in_specs=(param_specs, x_spec),

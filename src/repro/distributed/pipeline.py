@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
 
 
 def pipeline_apply(
@@ -92,7 +91,7 @@ def pipeline_apply(
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
     in_x = P()  # microbatch stream replicated across the pipeline axis
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pspec, in_x),

@@ -26,13 +26,13 @@ NEG_INF = -2.0**30
 
 def _decode_kernel(
     pos_ref,  # SMEM [B] int32 — current position per batch row (prefetched)
-    q_ref,  # [1, 1, D]
+    q_ref,  # [1, 1, G, D] — the G query heads sharing one KV head
     k_ref,  # [1, bk, D]
     v_ref,  # [1, bk, D]
-    o_ref,  # [1, 1, D]
-    m_scr,  # VMEM [1, 1]
-    l_scr,  # VMEM [1, 1]
-    acc_scr,  # VMEM [1, D]
+    o_ref,  # [1, 1, G, D]
+    m_scr,  # VMEM [G, 1]
+    l_scr,  # VMEM [G, 1]
+    acc_scr,  # VMEM [G, D]
     *,
     scale: float,
     window: Optional[int],
@@ -48,13 +48,13 @@ def _decode_kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     pos = pos_ref[pl.program_id(0)]
-    q = q_ref[0].astype(jnp.float32)  # [1, D]
+    q = q_ref[0, 0].astype(jnp.float32)  # [G, D]
     k = k_ref[0].astype(jnp.float32)  # [bk, D]
     v = v_ref[0].astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [1, bk]
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    ) * scale  # [G, bk]
+    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     mask = k_pos <= pos
     if window is not None:
         mask &= k_pos > pos - window
@@ -72,7 +72,7 @@ def _decode_kernel(
 
     @pl.when(ik == num_k_blocks - 1)
     def _finish():
-        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
+        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
             o_ref.dtype
         )
 
@@ -93,7 +93,10 @@ def decode_attention_fwd(
     g = hq // hkv
     assert sk % block_k == 0, (sk, block_k)
     nk = sk // block_k
-    grid = (b, hq, nk)
+    # one grid row per KV head: its G query heads form the block's last two
+    # dims (G, D), which span the whole array — legal on the 8x128 tiling
+    # for any G — and each KV block is read once per group, not per head
+    grid = (b, hkv, nk)
     kernel = functools.partial(
         _decode_kernel,
         scale=sm_scale if sm_scale is not None else d**-0.5,
@@ -105,26 +108,24 @@ def decode_attention_fwd(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda b_, h, ik, pos: (b_, h, 0)),
-            pl.BlockSpec(
-                (1, block_k, d), lambda b_, h, ik, pos, g_=g: (b_ * hkv + h // g_, ik, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_k, d), lambda b_, h, ik, pos, g_=g: (b_ * hkv + h // g_, ik, 0)
-            ),
+            pl.BlockSpec((1, 1, g, d), lambda b_, h, ik, pos: (b_, h, 0, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b_, h, ik, pos: (b_ * hkv + h, ik, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b_, h, ik, pos: (b_ * hkv + h, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b_, h, ik, pos: (b_, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, h, ik, pos: (b_, h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, d), jnp.float32),
         ],
     )
+    qg = q.reshape(b, hkv, g, d)
     kf = k.reshape(b * hkv, sk, d)
     vf = v.reshape(b * hkv, sk, d)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
-    )(positions.astype(jnp.int32), q, kf, vf)
+    )(positions.astype(jnp.int32), qg, kf, vf)
+    return out.reshape(b, hq, d)

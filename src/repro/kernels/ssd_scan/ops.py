@@ -25,12 +25,6 @@ def ssd(
     """Returns (y [B,S,H,P], final_state [B,H,P,N])."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    if initial_state is not None:
-        # kernel assumes zero init; fold a nonzero initial state in by
-        # treating it as a virtual chunk via the reference path
-        from .ref import ssd_chunked
-
-        return ssd_chunked(x, da, b, c, chunk, initial_state=initial_state)
     bsz, s, h, p = x.shape
     n = b.shape[-1]
     q = min(chunk, s)
@@ -45,6 +39,8 @@ def ssd(
     dak = da.reshape(bsz, nc, q, h).transpose(0, 3, 1, 2)
     bk = to_kernel(b, n)
     ck = to_kernel(c, n)
-    y, final_state = ssd_scan_fwd(xk, dak, bk, ck, interpret=interpret)
+    y, final_state = ssd_scan_fwd(
+        xk, dak, bk, ck, initial_state, interpret=interpret
+    )
     y = y.transpose(0, 2, 3, 1, 4).reshape(bsz, s, h, p)
     return y, final_state

@@ -31,6 +31,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from ..checkpoint import (
     checkpoint_files,
     decode_array,
+    tree_digest,
     unflatten_tree,
 )
 from ..configs.base import ModelConfig
@@ -127,6 +128,7 @@ class PilotTrainer:
 
         def train_chunk(cu_ctx, shard_du, ckpt_du, start_step, n_steps, batch, seq):
             params, opt_state = _restore_from_input(cu_ctx, ckpt_du)
+            restored_digest = tree_digest({"params": params, "opt": opt_state})
             # --- data from the co-located shard DU ---
             manifest = cu_ctx.input_manifest(shard_du)
             if any(rel.endswith(".bin") for rel in manifest):
@@ -148,11 +150,19 @@ class PilotTrainer:
             finally:
                 batches.close()
             # --- emit the next checkpoint DU ---
+            saved_digest = tree_digest({"params": params, "opt": opt_state})
             for rel, data in checkpoint_files(
                 start_step + n_steps, me.run_name, params, opt_state
             ).items():
                 cu_ctx.write_output(rel, data)
-            return {"losses": losses, "final_loss": losses[-1] if losses else None}
+            return {
+                "losses": losses,
+                "final_loss": losses[-1] if losses else None,
+                # equal digests across a chunk boundary: the next chunk
+                # resumed from exactly the state this one saved
+                "restored_digest": restored_digest,
+                "saved_digest": saved_digest,
+            }
 
         FUNCTIONS.register(f"train_chunk:{self.run_name}", train_chunk)
 
@@ -187,8 +197,17 @@ class PilotTrainer:
             replication_factor=self.ckpt_replication,
         )
         self.session.store.hset(f"ckpt:{self.run_name}", f"{0:08d}", fut.id)
+        self._drop_buffer(fut)
         self.ckpt_dus.append(fut)
         return fut
+
+    def _drop_buffer(self, ckpt: DUFuture) -> None:
+        """A checkpoint held by a Pilot-Data needs no in-process staging
+        copy too: it would double the host memory of every checkpoint in
+        the chain."""
+        du = self.session.ctx.lookup(ckpt.id)
+        if du.locations:
+            du.drop_local_buffer()
 
     # ----------------------------------------------------------------- run
     def submit_dag(self) -> List[Tuple[int, int, int, CUFuture]]:
@@ -240,9 +259,12 @@ class PilotTrainer:
                     "steps": (step, step + n),
                     "pilot": cu.pilot_id,
                     "losses": res["losses"],
+                    "restored_digest": res["restored_digest"],
+                    "saved_digest": res["saved_digest"],
                     "t_s_sim": cu.timings.sim_stage_s,
                 }
             )
+            self._drop_buffer(cu.output)
             self.ckpt_dus.append(cu.output)
             self.session.store.hset(
                 f"ckpt:{self.run_name}", f"{step + n:08d}", cu.output.id

@@ -3,11 +3,12 @@ against the pure-jnp ref oracles (kernels run in interpret mode on CPU)."""
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st
 
+from repro.kernels.cases import TOL_BF16, TOL_F32
+from repro.kernels.cases import max_normalized_error as _maxerr
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.flash_attention import flash_attention
@@ -17,20 +18,9 @@ from repro.kernels.rmsnorm.ref import rmsnorm_ref, rmsnorm_residual_ref
 from repro.kernels.ssd_scan import ssd
 from repro.kernels.ssd_scan.ref import ssd_chunked
 
-TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
-
 
 def _tol(dtype):
-    return TOL[jnp.bfloat16] if dtype == jnp.bfloat16 else TOL[jnp.float32]
-
-
-def _maxerr(a, b):
-    """Max error normalized by the ref magnitude (bf16 outputs quantize
-    proportionally to value scale, so absolute error alone misleads)."""
-    a = np.asarray(a, np.float32)
-    b = np.asarray(b, np.float32)
-    scale = max(1.0, float(np.abs(b).max()))
-    return float(np.abs(a - b).max()) / scale
+    return TOL_BF16 if dtype == jnp.bfloat16 else TOL_F32
 
 
 # ------------------------------------------------------------ flash attn
@@ -175,6 +165,27 @@ def test_ssd_state_continuity():
         initial_state=st1,
     )
     assert _maxerr(jnp.concatenate([y1, y2], axis=1), y_full) < 1e-4
+    assert _maxerr(st2, st_full) < 1e-4
+
+
+def test_ssd_kernel_takes_initial_state():
+    """The kernel starts its scan from a given state (no reference
+    fallback): second half from the first half's state == full run."""
+    rng = jax.random.PRNGKey(11)
+    b, s, h, p, n, chunk = 1, 128, 2, 32, 32, 32
+    ks = jax.random.split(rng, 4)
+    x = jax.random.normal(ks[0], (b, s, h, p))
+    da = -jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
+    B_ = jax.random.normal(ks[2], (b, s, h, n)) * 0.5
+    C_ = jax.random.normal(ks[3], (b, s, h, n)) * 0.5
+    y_full, st_full = ssd_chunked(x, da, B_, C_, chunk)
+    half = s // 2
+    _, st1 = ssd(x[:, :half], da[:, :half], B_[:, :half], C_[:, :half], chunk=chunk)
+    y2, st2 = ssd(
+        x[:, half:], da[:, half:], B_[:, half:], C_[:, half:], chunk=chunk,
+        initial_state=st1,
+    )
+    assert _maxerr(y2, y_full[:, half:]) < 1e-4
     assert _maxerr(st2, st_full) < 1e-4
 
 

@@ -19,8 +19,10 @@ SCRIPT = textwrap.dedent(
 
     from repro.distributed.pipeline import bubble_fraction, pipeline_apply
 
-    from repro.distributed.compat import make_mesh
-    mesh = make_mesh((4, 2), ("pod", "data"))
+    mesh = jax.make_mesh(
+        (4, 2), ("pod", "data"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )
     n_stages, n_micro, mb, d = 4, 8, 2, 16
 
     def stage_fn(w, x):

@@ -27,8 +27,10 @@ SCRIPT = textwrap.dedent(
     from repro.models import build_model
     from repro.optim import init_adamw
 
-    from repro.distributed.compat import make_mesh
-    mesh = make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh(
+        (2, 4), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )
     ctx = DistContext(mesh=mesh, batch_axes=("data",))
     out = {}
 

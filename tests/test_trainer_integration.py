@@ -62,6 +62,25 @@ def test_end_to_end_training_improves_loss(sess):
     assert "embed" in params
 
 
+def test_bf16_training_resumes_bit_exact_across_chunks(sess):
+    """bf16 params (the published dtype) ride the checkpoint-DU chain: every
+    chunk restores exactly the state the previous chunk saved."""
+    import dataclasses
+
+    sess.start_pilot_data(
+        service_url="sharedfs://cluster:pod0/bf16", affinity="cluster:pod0"
+    )
+    sess.start_pilot(resource_url="sim://cluster:pod0:host0", slots=1)
+    cfg = dataclasses.replace(tiny_cfg(), param_dtype="bfloat16")
+    tr = PilotTrainer(cfg, sess, run_name="t-bf16", **TINY)
+    tr.stage_data(affinities=["cluster:pod0"])
+    hist = tr.run()["history"]
+    assert len(hist) == 3
+    for prev, nxt in zip(hist, hist[1:]):
+        assert nxt["restored_digest"] == prev["saved_digest"]
+    assert tr.restore_params()["embed"]["table"].dtype.name == "bfloat16"
+
+
 def test_training_distributes_by_affinity(sess):
     """Shards placed at two sites → chunks run on the co-located pilots."""
     sess.start_pilot_data(
