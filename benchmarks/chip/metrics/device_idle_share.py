@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - union of the device op intervals / window, from the profiler trace."""
+
+UNIT, BETTER, LAYER, MOVES = "%", "lower", "device", "tokens_per_s"
+
+
+def value(run):
+    if run.trace is None:
+        return None
+    return 100.0 * run.trace.idle_share
