@@ -59,30 +59,26 @@ LOSS_BAND = 1.0
 
 
 class PhaseClock:
-    """Wall time and backend compile time of one phase."""
-
-    def __init__(self):
-        self.compile_s = 0.0
-        import jax.monitoring
-
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, name, secs, **_):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
+    """Wall time and backend compile time of one phase, run as a
+    ``smoke.<phase>`` span; the compile time is the program recorder's
+    ``jit.compile`` counter."""
 
     def run(self, name, fn, *args, **kwargs):
         import jax
 
-        c0, t0 = self.compile_s, time.perf_counter()
-        out = fn(*args, **kwargs)
+        from repro import tracing
+
+        c0, t0 = tracing.counters().get("jit.compile", 0.0), time.perf_counter()
+        with tracing.span(f"smoke.{name}"):
+            out = fn(*args, **kwargs)
         wall = time.perf_counter() - t0
+        compile_s = tracing.counters().get("jit.compile", 0.0) - c0
         stats = jax.devices()[0].memory_stats() or {}
         host_kib = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
         with open("/proc/self/statm") as f:
             host_rss = int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
         print(
-            f"phase {name}: wall_s={wall:.1f} compile_s={self.compile_s - c0:.1f} "
+            f"phase {name}: wall_s={wall:.1f} compile_s={compile_s:.1f} "
             f"peak_bytes_in_use={stats.get('peak_bytes_in_use', 'n/a')} "
             f"host_rss_bytes={host_rss} host_max_rss_bytes={host_kib * 1024}",
             flush=True,

@@ -40,6 +40,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import ml_dtypes  # noqa: F401 — registers bfloat16/float8 dtype names
 import numpy as np
 
+from .. import tracing
 from ..core import DataUnit, DataUnitDescription, DUState, RuntimeContext
 
 
@@ -79,23 +80,30 @@ _DTYPE_LINE = b"\x93REPRO-DTYPE "
 
 
 def encode_array(arr) -> bytes:
-    a = np.asarray(arr)
-    buf = io.BytesIO()
-    if a.dtype.isbuiltin == 2:  # a dtype registered by ml_dtypes
-        buf.write(_DTYPE_LINE + a.dtype.name.encode() + b"\n")
-        a = a.view(np.dtype(f"u{a.dtype.itemsize}"))
-    np.save(buf, a, allow_pickle=False)
-    return buf.getvalue()
+    """One leaf's bytes: its copy to the host and the .npy encoding, as a
+    ``ckpt.encode`` span."""
+    with tracing.span("ckpt.encode") as sp:
+        a = np.asarray(arr)
+        buf = io.BytesIO()
+        if a.dtype.isbuiltin == 2:  # a dtype registered by ml_dtypes
+            buf.write(_DTYPE_LINE + a.dtype.name.encode() + b"\n")
+            a = a.view(np.dtype(f"u{a.dtype.itemsize}"))
+        np.save(buf, a, allow_pickle=False)
+        data = buf.getvalue()
+        sp.attrs["bytes"] = len(data)
+    return data
 
 
 def decode_array(data: bytes) -> np.ndarray:
-    """Inverse of :func:`encode_array`, bit for bit, dtype included."""
-    buf = io.BytesIO(data)
-    if not data.startswith(_DTYPE_LINE):
-        return np.load(buf, allow_pickle=False)
-    line = buf.readline()
-    name = line[len(_DTYPE_LINE) : -1].decode()
-    return np.load(buf, allow_pickle=False).view(np.dtype(name))
+    """Inverse of :func:`encode_array`, bit for bit, dtype included; a
+    ``ckpt.decode`` span."""
+    with tracing.span("ckpt.decode", bytes=len(data)):
+        buf = io.BytesIO(data)
+        if not data.startswith(_DTYPE_LINE):
+            return np.load(buf, allow_pickle=False)
+        line = buf.readline()
+        name = line[len(_DTYPE_LINE) : -1].decode()
+        return np.load(buf, allow_pickle=False).view(np.dtype(name))
 
 
 def tree_digest(tree: Any) -> int:
@@ -165,12 +173,17 @@ class Checkpointer:
         # Sealing publishes the immutable manifest; with the fault manager
         # enabled the ReplicaManager now disperses the DU to its declared
         # replication_factor across failure domains — off this thread.
-        du.seal()
+        with tracing.span("du.seal", du=du.id):
+            du.seal()
         # the bytes now live in the Pilot-Data: a second, in-process copy
         # would double the host memory of every checkpoint
         du.drop_local_buffer()
         self.ctx.store.hset(f"ckpt:{self.run_name}", f"{step:08d}", du.id)
         return du
+
+    def _commit_async(self, du: DataUnit, step: int, target) -> DataUnit:
+        with tracing.span("ckpt.commit", du=du.id, step=step):
+            return self._commit(du, step, target)
 
     def save(
         self,
@@ -180,22 +193,33 @@ class Checkpointer:
         target=None,
         asynchronous: bool = False,
     ) -> DataUnit:
+        """Write ``(step, params, opt_state)`` as a checkpoint DU: a
+        ``ckpt.save`` span over the leaves' ``ckpt.encode``, the DU's
+        ``du.create`` (its chunk manifest and CRCs), and, unless
+        ``asynchronous``, its ``du.ingest`` and ``du.seal``; an
+        asynchronous commit is a ``ckpt.commit`` span on its own thread."""
         self.check()  # surface any completed async commit's failure NOW
-        desc = DataUnitDescription(
-            name=f"{self.run_name}.ckpt{step:08d}",
-            files=checkpoint_files(step, self.run_name, params, opt_state),
-            replication_factor=self.replication_factor,
-        )
-        du = DataUnit(desc, self.ctx.store)
-        self.ctx.register(du)
-        if asynchronous:
-            if self._pool is None:
-                self._pool = ThreadPoolExecutor(
-                    max_workers=1, thread_name_prefix="ckpt-commit"
+        with tracing.span("ckpt.save", step=step) as sp:
+            desc = DataUnitDescription(
+                name=f"{self.run_name}.ckpt{step:08d}",
+                files=checkpoint_files(step, self.run_name, params, opt_state),
+                replication_factor=self.replication_factor,
+            )
+            with tracing.span("du.create") as create:
+                du = DataUnit(desc, self.ctx.store)
+                create.attrs.update(du=du.id, bytes=du.size, chunks=du.n_chunks)
+            sp.attrs["du"] = du.id
+            self.ctx.register(du)
+            if asynchronous:
+                if self._pool is None:
+                    self._pool = ThreadPoolExecutor(
+                        max_workers=1, thread_name_prefix="ckpt-commit"
+                    )
+                self._pending.append(
+                    self._pool.submit(self._commit_async, du, step, target)
                 )
-            self._pending.append(self._pool.submit(self._commit, du, step, target))
-        else:
-            self._commit(du, step, target)
+            else:
+                self._commit(du, step, target)
         return du
 
     def check(self) -> None:

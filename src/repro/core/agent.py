@@ -29,6 +29,7 @@ import time
 import traceback
 from typing import Dict, Iterator, List, Optional, Tuple
 
+from .. import tracing
 from .affinity import match_affinity
 from .compute_unit import CUState, ComputeUnit, FUNCTIONS
 from .data_unit import DataUnit, DUState
@@ -82,15 +83,26 @@ class CUContext:
         """Read an input file — from the sandbox copy if staged, else via
         the logical link to a co-located PD."""
         sandbox = self.pilot.sandbox
-        if sandbox.has_du(du_id):
-            return sandbox.fetch_du_file(du_id, relpath)
-        du = self.ctx.lookup(du_id)
-        pd, linked = self.ctx.transfer_service.resolve_access(
-            du, self.pilot.affinity
-        )
-        if pd is not None:
-            return pd.fetch_du_file(du_id, relpath)
-        return du.read(relpath)  # pre-replica local buffer
+        with tracing.span("du.read", du=du_id, file=relpath) as sp:
+            if sandbox.has_du(du_id):
+                src, via = sandbox, "sandbox"
+                du = self.ctx.objects.get(du_id)
+            else:
+                du = self.ctx.lookup(du_id)
+                src, linked = self.ctx.transfer_service.resolve_access(
+                    du, self.pilot.affinity
+                )
+                via = "link" if linked else "replica"
+            if src is not None:
+                data = src.fetch_du_file(du_id, relpath)
+                chunks = len(du.chunks_for_file(relpath)) if du is not None else 0
+            else:
+                via, chunks = "buffer", 0  # pre-replica local buffer
+                data = du.read(relpath)
+            sp.attrs.update(via=via, bytes=len(data), chunks=chunks)
+        tracing.count("du.chunks_read", chunks)
+        tracing.count("du.bytes_read", len(data))
+        return data
 
     def input_manifest(self, du_id: str) -> Dict[str, int]:
         return self.ctx.lookup(du_id).manifest
@@ -597,16 +609,21 @@ class PilotAgent:
                 # Staging/Running CU's input chunks from under it
                 tm.pins.pin_inputs(cu)
             store.hset(f"cu:{cu.id}", "pilot", pilot.id)
-            cu.timings.stage_start = time.monotonic()
             # ---- stage inputs (pull-mode data management, §4.2) ----
             sim_stage = 0.0
+            stage = tracing.span("cu.stage_in", cu=cu.id)
             try:
-                for du_id in desc.input_data:
-                    du: DataUnit = ctx.lookup(du_id)
-                    sim_stage += ctx.transfer_service.stage_in(
-                        du, pilot.sandbox, pilot.affinity,
-                        use_cache=desc.cache_inputs,
+                with stage:
+                    cu.timings.stage_start = stage.start
+                    tracing.record(
+                        "cu.queue", cu.timings.submitted, stage.start, cu=cu.id
                     )
+                    for du_id in desc.input_data:
+                        du: DataUnit = ctx.lookup(du_id)
+                        sim_stage += ctx.transfer_service.stage_in(
+                            du, pilot.sandbox, pilot.affinity,
+                            use_cache=desc.cache_inputs,
+                        )
             except QuotaExceeded:
                 # Sandbox full and eviction blocked — typically by ANOTHER
                 # live consumer's pinned inputs.  That is backpressure,
@@ -637,7 +654,7 @@ class PilotAgent:
                             )
                         return
                 raise
-            cu.timings.stage_end = time.monotonic()
+            cu.timings.stage_end = stage.end
             cu.timings.sim_stage_s = sim_stage
             cu.timings.sim_prefetch_s = (
                 store.hget(f"cu:{cu.id}", "sim_prefetch_s", 0.0) or 0.0
@@ -646,18 +663,20 @@ class PilotAgent:
             if not is_dup:
                 cu._cas_state(CUState.STAGING, CUState.RUNNING)
             # ---- execute ----
-            cu.timings.run_start = time.monotonic()
-            fn = FUNCTIONS.resolve(desc.executable)
-            cu_ctx = CUContext(cu, pilot, ctx)
-            result = fn(cu_ctx, *desc.args, **desc.kwargs)
-            if cu_ctx.lost_stream():
-                # a live foreign attempt owns one of our output streams —
-                # its chunks are already published; decline the win and let
-                # that attempt complete (exactly-once for streamed bytes)
-                return
-            ctx.sleep_sim(desc.sim_compute_s)
+            with tracing.span("cu.run", cu=cu.id, executable=desc.executable) as run:
+                cu.timings.run_start = run.start
+                fn = FUNCTIONS.resolve(desc.executable)
+                cu_ctx = CUContext(cu, pilot, ctx)
+                result = fn(cu_ctx, *desc.args, **desc.kwargs)
+                if cu_ctx.lost_stream():
+                    # a live foreign attempt owns one of our output streams
+                    # — its chunks are already published; decline the win
+                    # and let that attempt complete (exactly-once for
+                    # streamed bytes)
+                    return
+                ctx.sleep_sim(desc.sim_compute_s)
             cu.timings.sim_compute_s = desc.sim_compute_s
-            cu.timings.run_end = time.monotonic()
+            cu.timings.run_end = run.end
             if self._dead.is_set():
                 return  # node died mid-flight: results are lost
             # reviewed: stale cache only delays the decline — the winner
@@ -694,19 +713,21 @@ class PilotAgent:
             # writes, then seal output DUs into the sandbox PD.  Only the
             # winner ever writes/seals — a FAILED attempt or losing
             # duplicate leaves output DUs untouched and unsealed. ----
-            cu_ctx.flush_outputs()
-            for du_id in desc.output_data:
-                du: DataUnit = ctx.lookup(du_id)
-                if not pilot.sandbox.has_du(du.id):
-                    # streaming DUs only pay for the not-yet-flushed tail
-                    # here (put_du skips chunks the sandbox already holds)
-                    ctx.transfer_service.ingest(du, pilot.sandbox)
-                du.seal()
-                if du.streaming:
-                    # end-of-stream: the writer claim has served its
-                    # purpose (the seal froze the content)
-                    store.hdel(f"du:{du.id}", "stream_writer")
-            store.hset(f"cu:{cu.id}", "state", CUState.DONE)
+            with tracing.span("cu.stage_out", cu=cu.id):
+                cu_ctx.flush_outputs()
+                for du_id in desc.output_data:
+                    du: DataUnit = ctx.lookup(du_id)
+                    if not pilot.sandbox.has_du(du.id):
+                        # streaming DUs only pay for the not-yet-flushed
+                        # tail here (put_du skips chunks the sandbox
+                        # already holds)
+                        ctx.transfer_service.ingest(du, pilot.sandbox)
+                    du.seal()
+                    if du.streaming:
+                        # end-of-stream: the writer claim has served its
+                        # purpose (the seal froze the content)
+                        store.hdel(f"du:{du.id}", "stream_writer")
+                store.hset(f"cu:{cu.id}", "state", CUState.DONE)
             store.hset(
                 f"cu:{cu.id}",
                 "timings",

@@ -104,10 +104,13 @@ class ComputeUnitDescription:
 
 @dataclasses.dataclass
 class CUTimings:
-    """Per-CU timing breakdown (the paper's Fig. 10 decomposition)."""
+    """Per-CU timing breakdown (the paper's Fig. 10 decomposition).
+
+    The host stamps (``time.monotonic()``) are the bounds of the CU's
+    ``cu.queue``, ``cu.stage_in`` and ``cu.run`` records in
+    :mod:`repro.tracing`."""
 
     submitted: float = 0.0
-    scheduled: float = 0.0
     stage_start: float = 0.0
     stage_end: float = 0.0
     run_start: float = 0.0

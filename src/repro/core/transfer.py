@@ -34,6 +34,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
+from .. import tracing
 from ..backends import KeyNotFound
 from .affinity import match_affinity
 from .cost_model import cheapest_replica
@@ -102,7 +103,6 @@ class TransferService:
         self._resolve_cache: Dict[Tuple[str, str], Tuple[int, Optional[str], bool]] = {}
         self._estimate_cache: Dict[Tuple[str, str, str], Tuple[int, float]] = {}
         self.cache_hits = 0
-        self.cache_misses = 0
         self._batch_ids = itertools.count()
         #: PDs purged after pilot death — never planned as a source or
         #: served from a cached resolution again
@@ -271,10 +271,13 @@ class TransferService:
 
     def ingest(self, du: DataUnit, dst: PilotData) -> float:
         """Initial staging of a freshly-described DU into its first PD."""
-        t0 = time.monotonic()
-        nbytes = dst.put_du(du)
-        sim = self.simulated_ingest_time(nbytes, dst)
-        self.ctx.sleep_sim(sim)
+        with tracing.span("du.ingest", du=du.id, pd=dst.id) as sp:
+            nbytes = dst.put_du(du)
+            sim = self.simulated_ingest_time(nbytes, dst)
+            self.ctx.sleep_sim(sim)
+            sp.attrs.update(bytes=nbytes, chunks=du.n_chunks)
+        tracing.count("du.chunks_written", du.n_chunks)
+        tracing.count("du.bytes_written", nbytes)
         self.record(
             TransferRecord(
                 du_id=du.id,
@@ -282,8 +285,8 @@ class TransferService:
                 dst_pd=dst.id,
                 nbytes=nbytes,
                 sim_seconds=sim,
-                wall_seconds=time.monotonic() - t0,
-                wall_start=t0,
+                wall_seconds=sp.end - sp.start,
+                wall_start=sp.start,
                 chunks=du.n_chunks,
             )
         )
@@ -575,7 +578,6 @@ class TransferService:
                     return None, False
                 if pd_id in self.ctx.objects:
                     return self.ctx.lookup(pd_id), linked
-            self.cache_misses += 1
         pd, linked = self._resolve_uncached(du, location)
         with self._lock:
             self._resolve_cache[key] = (ver, pd.id if pd else None, linked)
@@ -628,8 +630,6 @@ class TransferService:
             if hit is not None and hit[0] == ver:
                 self.cache_hits += 1
                 cost = hit[1]
-            else:
-                self.cache_misses += 1
         if cost is None:
             _, linked = self.resolve_access(du, location)
             if linked:
@@ -678,7 +678,25 @@ class TransferService:
         ``use_cache=False`` models the paper's PD-less naive mode: every CU
         re-stages the whole DU into its own sandbox from one source — the
         full monolithic transfer cost is charged each time and the sandbox
-        never becomes a replica."""
+        never becomes a replica.
+
+        Each call is a ``transfer.stage_in`` span whose ``bytes`` and
+        ``chunks`` are those this caller moved; ``linked`` is set where the
+        DU was reached by a logical link."""
+        with tracing.span(
+            "transfer.stage_in", du=du.id, bytes=0, chunks=0, linked=False
+        ) as sp:
+            return self._stage_in(du, sandbox, location, use_cache, prefix, sp)
+
+    def _stage_in(
+        self,
+        du: DataUnit,
+        sandbox: PilotData,
+        location: str,
+        use_cache: bool,
+        prefix: Optional[int],
+        sp: tracing.span,
+    ) -> float:
         if not use_cache:
             t0 = time.monotonic()
             already = sandbox.has_du(du.id)
@@ -691,6 +709,8 @@ class TransferService:
                 sim = self.simulated_ingest_time(du.size, sandbox)
                 if not already:
                     sandbox.put_du(du, register=False)
+            if not already:
+                sp.attrs.update(bytes=du.size, chunks=du.n_chunks)
             self.ctx.sleep_sim(sim)
             self.record(
                 TransferRecord(
@@ -744,6 +764,7 @@ class TransferService:
                         linked=True,
                     )
                 )
+                sp.attrs["linked"] = True
                 return total_sim
             missing = set(sandbox.missing_chunks(du))
             if target is not None:
@@ -776,6 +797,8 @@ class TransferService:
                     # re-waits on the published prefix and retries
                     return total_sim
                 total_sim += self._fetch_groups(du, sandbox, groups, location=location)
+                sp.attrs["chunks"] += len(mine)
+                sp.attrs["bytes"] += sum(g.nbytes for g in groups)
             finally:
                 with self._lock:
                     entries = self._inflight.get(key, [])
@@ -995,12 +1018,14 @@ class TransferService:
             if sim > 0.0:
                 self.ctx.store.hset(f"cu:{cu.id}", "sim_prefetch_s", sim)
 
-        return self.stage_in_bulk(
-            dus,
-            pilot.sandbox,
-            pilot.affinity,
-            pipelined=True,
-            batch_id=f"prefetch-{cu.id}",
-            claimed=claimed,
-            on_complete=attribute,
-        )
+        # a scheduler thread does this work, so the CU is named explicitly
+        with tracing.span("transfer.prefetch", cu=cu.id):
+            return self.stage_in_bulk(
+                dus,
+                pilot.sandbox,
+                pilot.affinity,
+                pipelined=True,
+                batch_id=f"prefetch-{cu.id}",
+                claimed=claimed,
+                on_complete=attribute,
+            )

@@ -15,6 +15,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from ..checkpoint import decode_array, unflatten_tree
 from ..models.registry import ModelApi
 
@@ -29,12 +30,15 @@ def params_from_input(cu_ctx, weights_du: str) -> Any:
     site's mem-tier cache.  The rest of the fleet then cold-starts from
     the promoted hot replica instead of re-pulling across the DCN (enable
     with ``tier_cache_bytes``/``tier_auto_promote`` on the Session).
+
+    A ``du.load`` span over each file's ``du.read`` and ``ckpt.decode``.
     """
-    items = {}
-    for rel in cu_ctx.input_manifest(weights_du):
-        if rel.startswith("params/") and rel.endswith(".npy"):
-            items[rel[7:-4]] = decode_array(cu_ctx.read_input(weights_du, rel))
-    return unflatten_tree(items)
+    with tracing.span("du.load", du=weights_du):
+        items = {}
+        for rel in cu_ctx.input_manifest(weights_du):
+            if rel.startswith("params/") and rel.endswith(".npy"):
+                items[rel[7:-4]] = decode_array(cu_ctx.read_input(weights_du, rel))
+        return unflatten_tree(items)
 
 
 def make_serve_step(api: ModelApi) -> Callable:
@@ -55,21 +59,24 @@ class DecodeRequest:
 
 
 class DecodeEngine:
-    """Minimal batched engine: static batch, greedy sampling.
+    """Minimal batched engine: static batch, greedy sampling; the
+    pure-compute layer of a serve CU.
 
-    Serving-side Pilot-Data integration (KV segments as DUs, prefix-cache
-    affinity) lives in ``repro.training.trainer`` / examples; this class is
-    the pure-compute layer.
+    Its host side is traced: ``engine.init``, ``engine.prefill`` and
+    ``engine.generate`` spans (the tracing and compiling of the step land
+    under the first call as ``jit.*`` records), and an ``engine.steps``
+    counter of ``serve_step`` dispatches.
     """
 
     def __init__(self, api: ModelApi, params: Any, batch: int, max_len: int):
-        self.api = api
-        self.params = params
-        self.batch = batch
-        self.max_len = max_len
-        self.cache = api.init_cache(batch, max_len)
-        self._step = jax.jit(make_serve_step(api))
-        self._pos = 0
+        with tracing.span("engine.init", batch=batch, max_len=max_len):
+            self.api = api
+            self.params = params
+            self.batch = batch
+            self.max_len = max_len
+            self.cache = api.init_cache(batch, max_len)
+            self._step = jax.jit(make_serve_step(api))
+            self._pos = 0
 
     @classmethod
     def from_cu_context(
@@ -82,6 +89,10 @@ class DecodeEngine:
     def prefill(self, tokens: jnp.ndarray) -> jnp.ndarray:
         """Feed prompt tokens (teacher-forced, one step at a time — a
         production engine would batch this; CPU tests keep prompts short)."""
+        with tracing.span("engine.prefill", tokens=tokens.shape[1]):
+            return self._feed(tokens)
+
+    def _feed(self, tokens: jnp.ndarray) -> jnp.ndarray:
         b, s = tokens.shape
         assert b == self.batch
         last = None
@@ -90,16 +101,19 @@ class DecodeEngine:
                 self.params, self.cache, tokens[:, i : i + 1], jnp.int32(self._pos)
             )
             self._pos += 1
+        tracing.count("engine.steps", s)
         return last
 
     def generate(self, tokens: jnp.ndarray, max_new_tokens: int) -> jnp.ndarray:
         """Greedy-decode continuation; returns [B, max_new_tokens]."""
-        cur = self.prefill(tokens)
-        out = [cur]
-        for _ in range(max_new_tokens - 1):
-            cur, self.cache = self._step(
-                self.params, self.cache, cur, jnp.int32(self._pos)
-            )
-            self._pos += 1
-            out.append(cur)
-        return jnp.concatenate(out, axis=1)
+        with tracing.span("engine.generate", tokens=max_new_tokens):
+            cur = self._feed(tokens)
+            out = [cur]
+            for _ in range(max_new_tokens - 1):
+                cur, self.cache = self._step(
+                    self.params, self.cache, cur, jnp.int32(self._pos)
+                )
+                self._pos += 1
+                out.append(cur)
+            tracing.count("engine.steps", max(max_new_tokens - 1, 0))
+            return jnp.concatenate(out, axis=1)

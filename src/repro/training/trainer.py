@@ -28,6 +28,7 @@ from __future__ import annotations
 import functools
 from typing import Any, Dict, List, Optional, Tuple
 
+from .. import tracing
 from ..checkpoint import (
     checkpoint_files,
     decode_array,
@@ -127,7 +128,8 @@ class PilotTrainer:
             )
 
         def train_chunk(cu_ctx, shard_du, ckpt_du, start_step, n_steps, batch, seq):
-            params, opt_state = _restore_from_input(cu_ctx, ckpt_du)
+            with tracing.span("train.restore", du=ckpt_du):
+                params, opt_state = _restore_from_input(cu_ctx, ckpt_du)
             restored_digest = tree_digest({"params": params, "opt": opt_state})
             # --- data from the co-located shard DU ---
             manifest = cu_ctx.input_manifest(shard_du)
@@ -144,17 +146,21 @@ class PilotTrainer:
             step_fn = jitted_step(1)
             losses = []
             try:
-                for _, b in zip(range(n_steps), batches):
-                    params, opt_state, metrics = step_fn(params, opt_state, b)
-                    losses.append(float(metrics["loss"]))
+                for step, b in zip(range(start_step, start_step + n_steps), batches):
+                    # dispatch and the loss's sync to the host
+                    with tracing.span("train.step", step=step):
+                        params, opt_state, metrics = step_fn(params, opt_state, b)
+                        losses.append(float(metrics["loss"]))
+                    tracing.count("train.steps")
             finally:
                 batches.close()
             # --- emit the next checkpoint DU ---
             saved_digest = tree_digest({"params": params, "opt": opt_state})
-            for rel, data in checkpoint_files(
-                start_step + n_steps, me.run_name, params, opt_state
-            ).items():
-                cu_ctx.write_output(rel, data)
+            with tracing.span("train.save", step=start_step + n_steps):
+                for rel, data in checkpoint_files(
+                    start_step + n_steps, me.run_name, params, opt_state
+                ).items():
+                    cu_ctx.write_output(rel, data)
             return {
                 "losses": losses,
                 "final_loss": losses[-1] if losses else None,
