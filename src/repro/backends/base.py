@@ -85,6 +85,22 @@ class StorageAdaptor(abc.ABC):
             key = key.replace("/", "%2F")
         return key
 
+    def get_into(self, key: str, out: memoryview, skip: int = 0) -> int:
+        """Copy bytes ``[skip, skip + len(out))`` of the object into ``out``;
+        returns how many were written (fewer at the object's end).
+
+        The default is :meth:`get` plus one copy; an adaptor that can read
+        straight into the caller's buffer overrides it."""
+        data = memoryview(self.get(key))[skip : skip + len(out)]
+        out[: len(data)] = data
+        return len(data)
+
+    @property
+    def native_get_into(self) -> bool:
+        """True when this adaptor overrides :meth:`get_into` (reads into the
+        buffer without an intermediate object)."""
+        return type(self).get_into is not StorageAdaptor.get_into
+
     def size(self, key: str) -> int:
         return len(self.get(key))
 

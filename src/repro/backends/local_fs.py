@@ -51,11 +51,28 @@ class LocalFSBackend(StorageAdaptor):
         return len(data)
 
     def get(self, key: str) -> bytes:
-        path = self._path(key)
-        if not os.path.exists(path):
-            raise KeyNotFound(f"{self.url}: {key}")
-        with open(path, "rb") as fh:
-            return fh.read()
+        try:
+            with open(self._path(key), "rb") as fh:
+                return fh.read()
+        except FileNotFoundError:
+            raise KeyNotFound(f"{self.url}: {key}") from None
+
+    def get_into(self, key: str, out: memoryview, skip: int = 0) -> int:
+        """Unbuffered ``readinto`` of the file straight into ``out``."""
+        try:
+            fh = open(self._path(key), "rb", buffering=0)
+        except FileNotFoundError:
+            raise KeyNotFound(f"{self.url}: {key}") from None
+        with fh:
+            if skip:
+                fh.seek(skip)
+            n = 0
+            while n < len(out):
+                got = fh.readinto(out[n:])
+                if not got:
+                    break
+                n += got
+        return n
 
     def delete(self, key: str) -> None:
         path = self._path(key)
@@ -76,10 +93,10 @@ class LocalFSBackend(StorageAdaptor):
         return os.path.exists(self._path(key))
 
     def size(self, key: str) -> int:
-        path = self._path(key)
-        if not os.path.exists(path):
-            raise KeyNotFound(f"{self.url}: {key}")
-        return os.path.getsize(path)
+        try:
+            return os.path.getsize(self._path(key))
+        except FileNotFoundError:
+            raise KeyNotFound(f"{self.url}: {key}") from None
 
 
 class SharedFSBackend(LocalFSBackend):

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import io
 import json
+import math
 import zlib
 from concurrent.futures import Future, ThreadPoolExecutor
 from concurrent.futures import wait as futures_wait
@@ -39,6 +40,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import ml_dtypes  # noqa: F401 — registers bfloat16/float8 dtype names
 import numpy as np
+from numpy.lib import format as npy_format
 
 from .. import tracing
 from ..core import DataUnit, DataUnitDescription, DUState, RuntimeContext
@@ -94,16 +96,36 @@ def encode_array(arr) -> bytes:
     return data
 
 
-def decode_array(data: bytes) -> np.ndarray:
+def decode_array(data) -> np.ndarray:
     """Inverse of :func:`encode_array`, bit for bit, dtype included; a
-    ``ckpt.decode`` span."""
+    ``ckpt.decode`` span.
+
+    The leaf is a view of ``data`` at the ``.npy`` data offset: it shares
+    memory with a ``bytearray`` (as ``fetch_du_file`` returns) and is one
+    writable copy of immutable ``bytes``."""
     with tracing.span("ckpt.decode", bytes=len(data)):
-        buf = io.BytesIO(data)
-        if not data.startswith(_DTYPE_LINE):
-            return np.load(buf, allow_pickle=False)
-        line = buf.readline()
-        name = line[len(_DTYPE_LINE) : -1].decode()
-        return np.load(buf, allow_pickle=False).view(np.dtype(name))
+        view = memoryview(data)
+        name, off = None, 0
+        if view[: len(_DTYPE_LINE)] == _DTYPE_LINE:
+            nl = data.index(b"\n", len(_DTYPE_LINE))
+            name, off = bytes(view[len(_DTYPE_LINE) : nl]).decode(), nl + 1
+        magic_end = off + npy_format.MAGIC_LEN
+        major, _ = npy_format.read_magic(io.BytesIO(bytes(view[off:magic_end])))
+        len_end = magic_end + (2 if major == 1 else 4)
+        head_end = len_end + int.from_bytes(view[magic_end:len_end], "little")
+        read_header = (
+            npy_format.read_array_header_1_0
+            if major == 1
+            else npy_format.read_array_header_2_0
+        )
+        shape, fortran_order, dtype = read_header(
+            io.BytesIO(bytes(view[magic_end:head_end]))
+        )
+        a = np.frombuffer(data, dtype, count=math.prod(shape), offset=head_end)
+        a = a.reshape(shape[::-1]).T if fortran_order else a.reshape(shape)
+        if not a.flags.writeable:  # immutable bytes: one copy, as np.load gave
+            a = a.copy(order="K")
+        return a.view(np.dtype(name)) if name is not None else a
 
 
 def tree_digest(tree: Any) -> int:

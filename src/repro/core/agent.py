@@ -81,7 +81,11 @@ class CUContext:
 
     def read_input(self, du_id: str, relpath: str) -> bytes:
         """Read an input file — from the sandbox copy if staged, else via
-        the logical link to a co-located PD."""
+        the logical link to a co-located PD.
+
+        A replica read returns the ``bytearray`` that its chunks were read
+        into (``path=into`` on the ``du.read`` span when the adaptor reads
+        straight into it, else ``copy``)."""
         sandbox = self.pilot.sandbox
         with tracing.span("du.read", du=du_id, file=relpath) as sp:
             if sandbox.has_du(du_id):
@@ -96,11 +100,14 @@ class CUContext:
             if src is not None:
                 data = src.fetch_du_file(du_id, relpath)
                 chunks = len(du.chunks_for_file(relpath)) if du is not None else 0
+                path = "into" if src.backend.native_get_into else "copy"
             else:
-                via, chunks = "buffer", 0  # pre-replica local buffer
+                via, chunks, path = "buffer", 0, "copy"  # pre-replica local buffer
                 data = du.read(relpath)
-            sp.attrs.update(via=via, bytes=len(data), chunks=chunks)
+            sp.attrs.update(via=via, bytes=len(data), chunks=chunks, path=path)
         tracing.count("du.chunks_read", chunks)
+        if path == "into":
+            tracing.count("du.chunks_read_into", chunks)
         tracing.count("du.bytes_read", len(data))
         return data
 

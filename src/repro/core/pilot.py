@@ -13,7 +13,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-from ..backends import StorageAdaptor, chunk_key, make_backend
+from ..backends import StorageAdaptor, StorageError, chunk_key, make_backend
 from .affinity import Topology
 from .coordination import CoordinationStore
 from .data_unit import DataUnit, _next_id
@@ -368,22 +368,29 @@ class PilotData:
             du, src, self.missing_chunks(du), register=register
         )
 
-    def fetch_du_file(self, du_id: str, relpath: str) -> bytes:
+    def fetch_du_file(self, du_id: str, relpath: str) -> bytearray:
         """Reassemble one DU file from the locally-held chunks covering its
-        byte range in the canonical stream."""
+        byte range in the canonical stream: one buffer of the file's size,
+        each chunk read straight into its slice (``get_into``)."""
         du: Optional[DataUnit] = self.ctx.objects.get(du_id) or self._du_objs.get(du_id)
         if du is None:
             raise KeyError(f"{self.url}: unknown DU {du_id!r}")
         start, end = du.file_range(relpath)
-        if start == end:
-            return b""
+        out = bytearray(end - start)
         csize = du.chunk_size
-        out = bytearray()
-        for i in du.chunks_for_file(relpath):
-            data = self.backend.get(chunk_key(du_id, i))
-            lo = i * csize
-            out += data[max(0, start - lo) : max(0, end - lo)]
-        return bytes(out)
+        with memoryview(out) as view:
+            for i in du.chunks_for_file(relpath):
+                lo = i * csize
+                a, b = max(start, lo), min(end, lo + csize)
+                got = self.backend.get_into(
+                    chunk_key(du_id, i), view[a - start : b - start], a - lo
+                )
+                if got != b - a:
+                    raise StorageError(
+                        f"{self.url}: chunk {i} of {du_id} is short "
+                        f"({got} of {b - a} bytes from offset {a - lo})"
+                    )
+        return out
 
     def verify_du(self, du: DataUnit) -> bool:
         """Checksum-verify every locally-held chunk against the DU's chunk

@@ -51,6 +51,21 @@ def test_delete_and_missing(backend):
     backend.delete("k")  # idempotent
 
 
+@pytest.mark.parametrize("skip,size", [(0, 10), (3, 4), (6, 10), (10, 2)])
+def test_get_into(backend, skip, size):
+    """Bytes [skip, skip + size) land in the buffer; fewer past the end."""
+    backend.put("g/k", b"0123456789")
+    buf = bytearray(b"#" * size)
+    got = backend.get_into("g/k", memoryview(buf), skip)
+    want = b"0123456789"[skip : skip + size]
+    assert got == len(want) and bytes(buf[:got]) == want
+    assert buf[got:] == b"#" * (size - got)
+    with pytest.raises(KeyNotFound):
+        backend.get_into("g/missing", memoryview(buf))
+    with pytest.raises(KeyNotFound):
+        backend.size("g/missing")
+
+
 def test_list_prefix(backend):
     if backend.flat_namespace:
         pytest.skip("flat namespace encodes separators")
