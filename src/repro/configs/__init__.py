@@ -5,6 +5,7 @@ from .base import (
     SHAPES,
     SMOKE_SHAPE,
     ShapeConfig,
+    SharedBlockConfig,
     SSMConfig,
     VLMConfig,
     reduced,
@@ -13,6 +14,6 @@ from .registry import ARCHS, cell_is_applicable, get_config, get_shape, list_arc
 
 __all__ = [
     "EncDecConfig", "ModelConfig", "MoEConfig", "SHAPES", "SMOKE_SHAPE",
-    "ShapeConfig", "SSMConfig", "VLMConfig", "reduced",
+    "ShapeConfig", "SharedBlockConfig", "SSMConfig", "VLMConfig", "reduced",
     "ARCHS", "cell_is_applicable", "get_config", "get_shape", "list_archs",
 ]

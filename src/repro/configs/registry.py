@@ -12,7 +12,7 @@ from .h2o_danube_1_8b import CONFIG as h2o_danube_1_8b
 from .gemma3_1b import CONFIG as gemma3_1b
 from .granite_moe_3b_a800m import CONFIG as granite_moe_3b_a800m
 from .qwen3_moe_30b_a3b import CONFIG as qwen3_moe_30b_a3b
-from .zamba2_1_2b import CONFIG as zamba2_1_2b
+from .zamba2_7b import CONFIG as zamba2_7b
 from .whisper_large_v3 import CONFIG as whisper_large_v3
 from .llava_next_mistral_7b import CONFIG as llava_next_mistral_7b
 from .mamba2_370m import CONFIG as mamba2_370m
@@ -26,7 +26,7 @@ ARCHS: Dict[str, ModelConfig] = {
         gemma3_1b,
         granite_moe_3b_a800m,
         qwen3_moe_30b_a3b,
-        zamba2_1_2b,
+        zamba2_7b,
         whisper_large_v3,
         llava_next_mistral_7b,
         mamba2_370m,

@@ -63,7 +63,6 @@ MICROBATCHES: Dict[Tuple[str, str], int] = {
     ("whisper-large-v3", "train_4k"): 2,
     ("mamba2-370m", "train_4k"): 4,
     ("granite-moe-3b-a800m", "train_4k"): 2,
-    ("zamba2-1.2b", "train_4k"): 2,
     ("llava-next-mistral-7b", "train_4k"): 2,
     ("granite-34b", "prefill_32k"): 2,
     ("gemma3-12b", "prefill_32k"): 2,

@@ -74,7 +74,7 @@ def _attention_sites(
         sites.append(site(cfg.n_layers, shape.seq_len, e.n_frames, False, None))
         return sites
     kinds = cfg.layer_kinds()
-    n_full = sum(1 for k in kinds if k in ("attn", "global", "moe", "shared_attn"))
+    n_full = sum(1 for k in kinds if k in ("attn", "global", "moe", "hybrid"))
     n_swa = sum(1 for k in kinds if k in ("swa", "swa_moe"))
     s = shape.seq_len
     if n_full:

@@ -23,14 +23,16 @@ NEG_INF = -2.0**30
 
 
 # ---------------------------------------------------------------- params
-def init_attention(rng, cfg: ModelConfig) -> Dict:
+def init_attention(rng, cfg: ModelConfig, d_in: Optional[int] = None) -> Dict:
+    """q/k/v from ``d_in`` (default d_model) and o back to d_model."""
     d, hd = cfg.d_model, cfg.head_dim_
+    d_in = d_in or d
     pdt = dt(cfg.param_dtype)
     ks = jax.random.split(rng, 4)
     return {
-        "q": init_dense(ks[0], d, cfg.n_heads * hd, pdt),
-        "k": init_dense(ks[1], d, cfg.n_kv_heads * hd, pdt),
-        "v": init_dense(ks[2], d, cfg.n_kv_heads * hd, pdt),
+        "q": init_dense(ks[0], d_in, cfg.n_heads * hd, pdt),
+        "k": init_dense(ks[1], d_in, cfg.n_kv_heads * hd, pdt),
+        "v": init_dense(ks[2], d_in, cfg.n_kv_heads * hd, pdt),
         "o": init_dense(ks[3], cfg.n_heads * hd, d, pdt),
     }
 
@@ -45,13 +47,14 @@ def gqa_attention(
     causal: bool = True,
     window: Optional[int] = None,
     kv_valid: Optional[jnp.ndarray] = None,  # [B, Sk] bool
+    scale: Optional[float] = None,  # of the scores; default D**-0.5
 ) -> jnp.ndarray:
     """Grouped-query attention with fp32 softmax; returns [B, Sq, Hq, D]."""
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
     g = hq // hkv
     q = q.reshape(b, sq, hkv, g, d)
-    scale = d**-0.5
+    scale = d**-0.5 if scale is None else scale
     scores = jnp.einsum(
         "bqhgd,bkhd->bhgqk", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
@@ -82,12 +85,16 @@ def attention_block(
     cache: Optional[Dict] = None,
     cache_index: Optional[jnp.ndarray] = None,
     impl: str = "ref",
+    scale: Optional[float] = None,
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """Full attention sub-block: qkv proj → rope → attention → out proj.
 
-    With ``cache``/``cache_index``: single-token decode — x is [B, 1, d],
+    x is [B, S, d_in] for the projections' input width.  ``scale`` of the
+    scores (default head_dim**-0.5) may be set on the reference path only.
+    With ``cache``/``cache_index``: single-token decode — x is [B, 1, d_in],
     the KV cache is updated in place (functionally) at ``cache_index``.
     """
+    assert scale is None or impl == "ref", f"impl {impl!r} scales by head_dim**-0.5"
     b, s, _ = x.shape
     hd = cfg.head_dim_
     theta = rope_theta if rope_theta is not None else cfg.rope_theta
@@ -167,6 +174,7 @@ def attention_block(
                 causal=causal,
                 window=window,
                 kv_valid=kv_valid,
+                scale=scale,
             )
     else:
         if impl == "flash":
@@ -183,7 +191,8 @@ def attention_block(
             )
         else:
             out = gqa_attention(
-                q, k, v, positions, positions, causal=causal, window=window
+                q, k, v, positions, positions, causal=causal, window=window,
+                scale=scale,
             )
     out = dense(out.reshape(b, s, cfg.n_heads * hd), params["o"])
     return out, new_cache

@@ -11,6 +11,7 @@ Conventions:
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -28,11 +29,17 @@ def init_rmsnorm(d: int, dtype) -> Dict:
     return {"scale": jnp.zeros((d,), dtype=dtype)}
 
 
-def rms_norm(x: jnp.ndarray, params: Dict, eps: float = 1e-6) -> jnp.ndarray:
-    """RMSNorm with (1+scale) parameterization (gemma-style, zero-init)."""
+def rms_norm(
+    x: jnp.ndarray, params: Dict, eps: float = 1e-6, groups: int = 1
+) -> jnp.ndarray:
+    """RMSNorm with (1+scale) parameterization (gemma-style, zero-init);
+    with ``groups``, each of that many equal slices of the last axis is
+    normalized on its own (Mamba2's gated norm with n_groups > 1)."""
     xf = x.astype(jnp.float32)
+    if groups > 1:
+        xf = xf.reshape(*x.shape[:-1], groups, x.shape[-1] // groups)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    normed = xf * jax.lax.rsqrt(var + eps)
+    normed = (xf * jax.lax.rsqrt(var + eps)).reshape(x.shape)
     out = normed * (1.0 + params["scale"].astype(jnp.float32))
     return out.astype(x.dtype)
 
@@ -83,7 +90,7 @@ def init_mlp(rng, cfg: ModelConfig, d_ff: Optional[int] = None) -> Dict:
     d, f = cfg.d_model, d_ff or cfg.d_ff
     pdt = dt(cfg.param_dtype)
     ks = jax.random.split(rng, 3)
-    if cfg.mlp_type == "swiglu":
+    if cfg.mlp_type != "gelu":
         return {
             "gate": init_dense(ks[0], d, f, pdt),
             "up": init_dense(ks[1], d, f, pdt),
@@ -95,13 +102,35 @@ def init_mlp(rng, cfg: ModelConfig, d_ff: Optional[int] = None) -> Dict:
     }
 
 
-def mlp(x: jnp.ndarray, params: Dict, cfg: ModelConfig) -> jnp.ndarray:
+def init_adapter(rng, d: int, rank: int, f: int, dtype) -> Dict:
+    """A rank-``rank`` term for a gated MLP's gate and up projections:
+    x → (x·down)·gate and (x·down)·up."""
+    ks = jax.random.split(rng, 3)
+    return {
+        "down": init_dense(ks[0], d, rank, dtype),
+        "gate": init_dense(ks[1], rank, f, dtype),
+        "up": init_dense(ks[2], rank, f, dtype),
+    }
+
+
+def mlp(
+    x: jnp.ndarray, params: Dict, cfg: ModelConfig, adapter: Optional[Dict] = None
+) -> jnp.ndarray:
+    """The MLP; ``adapter`` (gated kinds) adds its low-rank term to the gate
+    and up projections (Zamba2's per-site adapter)."""
+    if cfg.mlp_type == "gelu":
+        return dense(jax.nn.gelu(dense(x, params["up"])), params["down"])
+    low = dense(x, adapter["down"]) if adapter is not None else None
+
+    def proj(name):
+        y = dense(x, params[name])
+        return y if low is None else y + dense(low, adapter[name])
+
     if cfg.mlp_type == "swiglu":
-        return dense(
-            jax.nn.silu(dense(x, params["gate"])) * dense(x, params["up"]),
-            params["down"],
-        )
-    return dense(jax.nn.gelu(dense(x, params["up"])), params["down"])
+        act = jax.nn.silu
+    else:  # geglu: exact (erf) GELU
+        act = functools.partial(jax.nn.gelu, approximate=False)
+    return dense(act(proj("gate")) * proj("up"), params["down"])
 
 
 # -------------------------------------------------------------- embeddings
@@ -133,6 +162,8 @@ def init_embedding(rng, cfg: ModelConfig) -> Dict:
 
 def embed(tokens: jnp.ndarray, params: Dict, cfg: ModelConfig) -> jnp.ndarray:
     x = params["table"].astype(dt(cfg.compute_dtype))[tokens]
+    if not cfg.scale_embedding:
+        return x
     # gemma-style sqrt(d) scaling keeps tied-embedding logits sane
     return x * jnp.asarray(cfg.d_model**0.5, dtype=x.dtype)
 

@@ -148,6 +148,12 @@ def ssd_chunked(
 
 
 # ----------------------------------------------------------- block forward
+def _dt(raw: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """dt = softplus(dt_proj(u) + dt_bias), floored at ``ssm.dt_min``."""
+    dt_ = jax.nn.softplus(raw)
+    return jnp.maximum(dt_, _ssm(cfg).dt_min) if _ssm(cfg).dt_min else dt_
+
+
 def _causal_conv(xBC: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray):
     """Depthwise causal conv over the sequence dim; xBC [B,S,Ch], w [W,Ch]."""
     width = w.shape[0]
@@ -188,9 +194,7 @@ def mamba_block(
     rep = h // g
     B_h = jnp.repeat(B_, rep, axis=2)
     C_h = jnp.repeat(C_, rep, axis=2)
-    dt_ = jax.nn.softplus(
-        dt_raw.astype(jnp.float32) + params["dt_bias"][None, None, :]
-    )  # [B,S,H]
+    dt_ = _dt(dt_raw.astype(jnp.float32) + params["dt_bias"][None, None, :], cfg)  # [B,S,H]
     A = -jnp.exp(params["A_log"])  # [H]
     dA = dt_ * A[None, None, :]
     xdt = x.astype(jnp.float32) * dt_[..., None]
@@ -206,8 +210,9 @@ def mamba_block(
         )
     y = y + params["D"][None, None, :, None] * x.astype(jnp.float32)
     y = y.reshape(b, s, dims["d_inner"]).astype(u.dtype)
+    # gated RMSNorm over groups of d_inner / n_groups channels
     y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(u.dtype),
-                 params["gate_norm"], cfg.norm_eps)
+                 params["gate_norm"], cfg.norm_eps, groups=g)
     return y @ params["out_proj"]["w"].astype(u.dtype), final_state
 
 
@@ -268,9 +273,7 @@ def mamba_decode_step(
     rep = h // g
     B_h = jnp.repeat(B_, rep, axis=1)  # [B,H,N]
     C_h = jnp.repeat(C_, rep, axis=1)
-    dt_ = jax.nn.softplus(
-        dt_raw.astype(jnp.float32) + params["dt_bias"][None, :]
-    )  # [B,H]
+    dt_ = _dt(dt_raw.astype(jnp.float32) + params["dt_bias"][None, :], cfg)  # [B,H]
     A = -jnp.exp(params["A_log"])
     dA = jnp.exp(dt_ * A[None, :])  # [B,H]
     state = cache["ssm"] * dA[:, :, None, None] + jnp.einsum(
@@ -282,6 +285,7 @@ def mamba_decode_step(
         (y * jax.nn.silu(z.astype(jnp.float32)).astype(u.dtype))[:, None, :],
         params["gate_norm"],
         cfg.norm_eps,
+        groups=g,
     )[:, 0]
     out = (y @ params["out_proj"]["w"].astype(u.dtype))[:, None, :]
     return out, {

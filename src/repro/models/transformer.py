@@ -1,15 +1,18 @@
-"""Generic pattern-based LM covering the dense / moe / hybrid / ssm / vlm
+"""Generic layer-list LM covering the dense / moe / hybrid / ssm / vlm
 families.
 
-Depth is organized as ``n_groups`` repetitions of ``cfg.pattern`` (plus an
-unrolled tail when depth % pattern ≠ 0) and executed with ``lax.scan`` over
-stacked per-group parameters — one pattern body in the HLO regardless of
-depth, which keeps 512-device SPMD compiles tractable and is also what makes
-per-layer remat policies cheap.
+Depth is organized as the config's runs (``ModelConfig.layer_runs``): each
+run repeats a period of layer kinds and is executed with ``lax.scan`` over
+per-layer parameters stacked on a leading axis — one period body in the HLO
+per run regardless of depth, which keeps 512-device SPMD compiles tractable
+and is also what makes per-layer remat policies cheap.  A cycled pattern is
+one run; an explicit layer list (zamba2) scans each repeated stretch.
 
-"shared_attn" blocks (zamba2) use ONE parameter set closed over by the scan
-body — the weights are shared across occurrences while each occurrence keeps
-its own KV cache slice.
+The parameters and the cache of the layer that opens a run's period at
+position ``i`` live under ``groups/pos<first layer of that position>``.
+"hybrid" layers (zamba2) run one of ``params["shared"]``'s blocks, closed
+over by the scan body, with their own adapter and output projection, and
+keep their own KV cache beside their mixer's SSM state.
 """
 
 from __future__ import annotations
@@ -21,12 +24,22 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from .attention import attention_block, init_attention
-from .layers import chunked_cross_entropy, dt, embed, init_embedding, init_mlp, init_rmsnorm, mlp, rms_norm, unembed
+from .layers import (
+    chunked_cross_entropy,
+    dense,
+    dt,
+    embed,
+    init_adapter,
+    init_dense,
+    init_embedding,
+    init_mlp,
+    init_rmsnorm,
+    mlp,
+    rms_norm,
+    unembed,
+)
 from .mamba2 import init_mamba_block, mamba_block, mamba_decode_step, mamba_dims
 from .moe import init_moe
-
-ATTN_KINDS = ("attn", "global", "swa", "moe", "swa_moe", "shared_attn")
-
 
 def _kind_window(kind: str, cfg: ModelConfig) -> Optional[int]:
     return cfg.sliding_window if kind in ("swa", "swa_moe") else None
@@ -38,15 +51,25 @@ def _kind_theta(kind: str, cfg: ModelConfig) -> float:
     return cfg.rope_theta
 
 
+def _run(tree: Dict, first: int, period: int) -> Dict:
+    """The entries of ``tree`` (``pos<layer>`` keyed) of one run."""
+    return {f"pos{i}": tree[f"pos{i}"] for i in range(first, first + period)}
+
+
 # ------------------------------------------------------------------- init
 def init_block(rng, kind: str, cfg: ModelConfig, ep: int = 1) -> Dict:
     ks = jax.random.split(rng, 4)
     pdt = dt(cfg.param_dtype)
-    if kind == "mamba":
-        return {
+    if kind in ("mamba", "hybrid"):
+        block = {
             "ln1": init_rmsnorm(cfg.d_model, pdt),
             "mamba": init_mamba_block(ks[0], cfg),
         }
+        if kind == "hybrid":  # the site's own parts of its shared block
+            d = cfg.d_model
+            block["adapter"] = init_adapter(ks[1], d, cfg.shared.adapter_rank, cfg.d_ff, pdt)
+            block["linear"] = init_dense(ks[2], d, d, pdt)
+        return block
     block = {
         "ln1": init_rmsnorm(cfg.d_model, pdt),
         "attn": init_attention(ks[0], cfg),
@@ -59,39 +82,77 @@ def init_block(rng, kind: str, cfg: ModelConfig, ep: int = 1) -> Dict:
     return block
 
 
+def init_shared_block(rng, cfg: ModelConfig) -> Dict:
+    """One of zamba2's shared transformer blocks: attention over
+    concat[h, e0] (width 2·d_model) and a gated MLP."""
+    ks = jax.random.split(rng, 2)
+    pdt = dt(cfg.param_dtype)
+    return {
+        "ln1": init_rmsnorm(2 * cfg.d_model, pdt),
+        "attn": init_attention(ks[0], cfg, d_in=2 * cfg.d_model),
+        "ln2": init_rmsnorm(cfg.d_model, pdt),
+        "mlp": init_mlp(ks[1], cfg),
+    }
+
+
 def init_lm(rng, cfg: ModelConfig, ep: int = 1) -> Dict:
-    pat = cfg.pattern
-    g = cfg.n_layers // len(pat)
-    tail_kinds = cfg.layer_kinds()[g * len(pat) :]
+    kinds = cfg.layer_kinds()
+    groups = {}
+    for first, period, reps in cfg.layer_runs():
+        for i in range(first, first + period):
+            keys = jax.random.split(jax.random.fold_in(rng, 100 + i), reps)
+            groups[f"pos{i}"] = jax.vmap(
+                lambda k, kd=kinds[i]: init_block(k, kd, cfg, ep)
+            )(keys)
     params: Dict[str, Any] = {
         "embed": init_embedding(jax.random.fold_in(rng, 0), cfg),
         "final_norm": init_rmsnorm(cfg.d_model, dt(cfg.param_dtype)),
+        "groups": groups,
     }
-    if g > 0:
-        groups = {}
-        for i, kind in enumerate(pat):
-            if kind == "shared_attn":
-                continue  # lives in params["shared"], not per-group
-            keys = jax.random.split(jax.random.fold_in(rng, 100 + i), g)
-            groups[f"pos{i}"] = jax.vmap(
-                lambda k, kd=kind: init_block(k, kd, cfg, ep)
-            )(keys)
-        params["groups"] = groups
-    if "shared_attn" in pat:
-        params["shared"] = init_block(
-            jax.random.fold_in(rng, 999), "shared_attn", cfg, ep
-        )
-    if tail_kinds:
-        params["tail"] = {
-            f"pos{i}": init_block(
-                jax.random.fold_in(rng, 200 + i), kind, cfg, ep
-            )
-            for i, kind in enumerate(tail_kinds)
+    if cfg.shared is not None:
+        params["shared"] = {
+            f"block{b}": init_shared_block(jax.random.fold_in(rng, 900 + b), cfg)
+            for b in range(cfg.shared.n_blocks)
         }
     return params
 
 
 # ----------------------------------------------------------------- blocks
+def shared_block(
+    sp: Dict,
+    bp: Dict,
+    x: jnp.ndarray,
+    e0: jnp.ndarray,
+    positions: jnp.ndarray,
+    cfg: ModelConfig,
+    impl: str = "ref",
+    cache: Optional[Dict] = None,
+    cache_index: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, Optional[Dict]]:
+    """Zamba2's shared block ``sp`` at one site (``bp``: its adapter and
+    output projection): norm(concat[x, e0]) → attention (rope over the
+    whole head, scores scaled by (head_dim/2)^-0.5) → norm → gated MLP with
+    the site's adapter → the site's projection.  No residual inside: the
+    output goes to the mixer's input only.  Returns (out, new KV cache)."""
+    with jax.named_scope("zamba2.shared_block"):
+        h = rms_norm(jnp.concatenate([x, e0], axis=-1), sp["ln1"], cfg.norm_eps)
+        a, new_cache = attention_block(
+            sp["attn"],
+            h,
+            positions,
+            cfg,
+            causal=True,
+            rope_theta=cfg.rope_theta,
+            cache=cache,
+            cache_index=cache_index,
+            impl=impl,
+            scale=(cfg.head_dim_ / 2) ** -0.5,
+        )
+        h = rms_norm(a, sp["ln2"], cfg.norm_eps)
+        out = dense(mlp(h, sp["mlp"], cfg, adapter=bp["adapter"]), bp["linear"])
+    return out, new_cache
+
+
 def apply_block(
     kind: str,
     bp: Dict,
@@ -99,21 +160,26 @@ def apply_block(
     positions: jnp.ndarray,
     cfg: ModelConfig,
     shared: Optional[Dict] = None,
+    e0: Optional[jnp.ndarray] = None,
     impl: str = "ref",
     ep_axis: Optional[str] = None,
     cache: Optional[Dict] = None,
     cache_index: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[Dict]]:
-    """One block; returns (x, aux_loss, new_cache_slice)."""
+    """One block; returns (x, aux_loss, new_cache_slice).  ``shared`` is the
+    shared block a hybrid layer runs, and ``e0`` the input embedding."""
     aux = jnp.zeros((), dtype=jnp.float32)
-    if kind == "shared_attn":
-        bp = shared
-    if kind == "mamba":
-        h = rms_norm(x, bp["ln1"], cfg.norm_eps)
-        if cache is not None:
-            out, new_state = mamba_decode_step(bp["mamba"], h, cache, cfg)
-            return x + out, aux, new_state
-        out, _ = mamba_block(bp["mamba"], h, cfg, impl=impl)
+    if kind in ("mamba", "hybrid"):
+        inp, kv = x, None
+        if kind == "hybrid":
+            t, kv = shared_block(shared, bp, x, e0, positions, cfg, impl, cache, cache_index)
+            inp = x + t
+        h = rms_norm(inp, bp["ln1"], cfg.norm_eps)
+        with jax.named_scope("mamba2.mixer"):
+            if cache is not None:
+                out, new_state = mamba_decode_step(bp["mamba"], h, cache, cfg)
+                return x + out, aux, new_state if kv is None else {**new_state, **kv}
+            out, _ = mamba_block(bp["mamba"], h, cfg, impl=impl)
         return x + out, aux, None
 
     window = _kind_window(kind, cfg)
@@ -142,30 +208,36 @@ def apply_block(
     return x + ff, aux, new_cache
 
 
-def _apply_pattern(
+def _apply_run(
     x: jnp.ndarray,
     gp: Dict,
-    kinds: Tuple[str, ...],
+    first: int,
+    period: int,
     positions: jnp.ndarray,
     cfg: ModelConfig,
     shared: Optional[Dict],
+    e0: jnp.ndarray,
     impl: str,
     ep_axis: Optional[str],
     caches: Optional[Dict] = None,
     cache_index: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[Dict]]:
+    """One period of the run that starts at layer ``first``."""
+    kinds = cfg.layer_kinds()
     aux = jnp.zeros((), dtype=jnp.float32)
     new_caches = {} if caches is not None else None
-    for i, kind in enumerate(kinds):
-        bp = gp.get(f"pos{i}") if kind != "shared_attn" else None
-        cache_i = caches.get(f"pos{i}") if caches is not None else None
+    for i in range(first, first + period):
+        key = f"pos{i}"
+        block = shared[f"block{cfg.shared_block(i)}"] if kinds[i] == "hybrid" else None
+        cache_i = caches.get(key) if caches is not None else None
         x, a, nc = apply_block(
-            kind,
-            bp,
+            kinds[i],
+            gp.get(key),
             x,
             positions,
             cfg,
-            shared=shared,
+            shared=block,
+            e0=e0,
             impl=impl,
             ep_axis=ep_axis,
             cache=cache_i,
@@ -173,7 +245,7 @@ def _apply_pattern(
         )
         aux = aux + a
         if new_caches is not None:
-            new_caches[f"pos{i}"] = nc
+            new_caches[key] = nc
     return x, aux, new_caches
 
 
@@ -201,18 +273,17 @@ def forward(
     if prefix_embeds is not None:
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
     x = constrain(x, "residual")
+    e0 = x
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-    pat = cfg.pattern
-    g = cfg.n_layers // len(pat)
     shared = params.get("shared")
     aux_total = jnp.zeros((), dtype=jnp.float32)
 
-    if g > 0:
-        def body(carry, gp):
+    for first, period, _ in cfg.layer_runs():
+        def body(carry, gp, first=first, period=period):
             x, aux = carry
-            x, a, _ = _apply_pattern(
-                x, gp, pat, positions, cfg, shared, impl, ep_axis
+            x, a, _ = _apply_run(
+                x, gp, first, period, positions, cfg, shared, e0, impl, ep_axis
             )
             return (constrain(x, "residual"), aux + a), None
 
@@ -220,14 +291,9 @@ def forward(
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.nothing_saveable
             )
-        (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), params["groups"])
-
-    tail_kinds = cfg.layer_kinds()[g * len(pat) :]
-    if tail_kinds:
-        x, a, _ = _apply_pattern(
-            x, params["tail"], tuple(tail_kinds), positions, cfg, shared, impl, ep_axis
+        (x, aux_total), _ = jax.lax.scan(
+            body, (x, aux_total), _run(params["groups"], first, period)
         )
-        aux_total = aux_total + a
 
     if last_only:
         x = x[:, -1:, :]
@@ -275,25 +341,23 @@ def loss_fn(
 
 
 # ----------------------------------------------------------------- decode
-def _init_block_cache(
-    kind: str, cfg: ModelConfig, batch: int, max_len: int
-) -> Dict:
-    if kind == "mamba":
-        dims = mamba_dims(cfg)
-        gn2 = 2 * dims["n_groups"] * dims["d_state"]
-        return {
-            "ssm": jnp.zeros(
-                (batch, dims["n_heads"], dims["head_dim"], dims["d_state"]),
-                dtype=jnp.float32,
-            ),
-            "conv_x": jnp.zeros(
-                (batch, dims["conv_width"] - 1, dims["d_inner"]),
-                dtype=jnp.float32,
-            ),
-            "conv_bc": jnp.zeros(
-                (batch, dims["conv_width"] - 1, gn2), dtype=jnp.float32
-            ),
-        }
+def _ssm_cache(cfg: ModelConfig, batch: int) -> Dict:
+    dims = mamba_dims(cfg)
+    gn2 = 2 * dims["n_groups"] * dims["d_state"]
+    return {
+        "ssm": jnp.zeros(
+            (batch, dims["n_heads"], dims["head_dim"], dims["d_state"]),
+            dtype=jnp.float32,
+        ),
+        "conv_x": jnp.zeros(
+            (batch, dims["conv_width"] - 1, dims["d_inner"]),
+            dtype=jnp.float32,
+        ),
+        "conv_bc": jnp.zeros((batch, dims["conv_width"] - 1, gn2), dtype=jnp.float32),
+    }
+
+
+def _kv_cache(kind: str, cfg: ModelConfig, batch: int, max_len: int) -> Dict:
     cdt = dt(cfg.compute_dtype)
     # SWA blocks never attend beyond their window → ring buffer of window
     # length (5/6 of gemma3's layers: 32k → 1k cache rows)
@@ -312,25 +376,29 @@ def _init_block_cache(
     return {"k": jnp.zeros(shape, dtype=cdt), "v": jnp.zeros(shape, dtype=cdt)}
 
 
+def _init_block_cache(
+    kind: str, cfg: ModelConfig, batch: int, max_len: int
+) -> Dict:
+    """A layer's decode state: fp32 SSM and conv state for a mixer, KV for
+    attention; a hybrid layer holds both."""
+    if kind == "mamba":
+        return _ssm_cache(cfg, batch)
+    if kind == "hybrid":
+        return {**_ssm_cache(cfg, batch), **_kv_cache(kind, cfg, batch, max_len)}
+    return _kv_cache(kind, cfg, batch, max_len)
+
+
 def init_lm_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
-    pat = cfg.pattern
-    g = cfg.n_layers // len(pat)
-    cache: Dict[str, Any] = {}
-    if g > 0:
-        groups = {}
-        for i, kind in enumerate(pat):
-            one = _init_block_cache(kind, cfg, batch, max_len)
+    """One dense tree for the whole depth, shaped as the parameters' runs."""
+    kinds = cfg.layer_kinds()
+    groups = {}
+    for first, period, reps in cfg.layer_runs():
+        for i in range(first, first + period):
+            one = _init_block_cache(kinds[i], cfg, batch, max_len)
             groups[f"pos{i}"] = jax.tree.map(
-                lambda l: jnp.broadcast_to(l[None], (g, *l.shape)).copy(), one
+                lambda l: jnp.broadcast_to(l[None], (reps, *l.shape)).copy(), one
             )
-        cache["groups"] = groups
-    tail_kinds = cfg.layer_kinds()[g * len(pat) :]
-    if tail_kinds:
-        cache["tail"] = {
-            f"pos{i}": _init_block_cache(kind, cfg, batch, max_len)
-            for i, kind in enumerate(tail_kinds)
-        }
-    return cache
+    return {"groups": groups}
 
 
 def decode_step(
@@ -345,25 +413,26 @@ def decode_step(
     """One-token decode against a KV/SSM cache; returns (logits [B,1,V],
     new cache)."""
     x = embed(tokens, params["embed"], cfg)
+    e0 = x
     b = x.shape[0]
     positions = jnp.broadcast_to(
         pos_index.astype(jnp.int32)[None, None], (b, 1)
     )
-    pat = cfg.pattern
-    g = cfg.n_layers // len(pat)
     shared = params.get("shared")
-    new_cache: Dict[str, Any] = {}
+    new_groups: Dict[str, Any] = {}
 
-    if g > 0:
-        def body(x, xs):
+    for first, period, _ in cfg.layer_runs():
+        def body(x, xs, first=first, period=period):
             gp, gc = xs
-            x, _, nc = _apply_pattern(
+            x, _, nc = _apply_run(
                 x,
                 gp,
-                pat,
+                first,
+                period,
                 positions,
                 cfg,
                 shared,
+                e0,
                 impl,
                 ep_axis,
                 caches=gc,
@@ -371,27 +440,16 @@ def decode_step(
             )
             return x, nc
 
-        x, new_groups = jax.lax.scan(
-            body, x, (params["groups"], cache["groups"])
-        )
-        new_cache["groups"] = new_groups
-
-    tail_kinds = cfg.layer_kinds()[g * len(pat) :]
-    if tail_kinds:
-        x, _, nt = _apply_pattern(
+        x, nc = jax.lax.scan(
+            body,
             x,
-            params.get("tail", {}),
-            tuple(tail_kinds),
-            positions,
-            cfg,
-            shared,
-            impl,
-            ep_axis,
-            caches=cache["tail"],
-            cache_index=pos_index,
+            (
+                _run(params["groups"], first, period),
+                _run(cache["groups"], first, period),
+            ),
         )
-        new_cache["tail"] = nt
+        new_groups.update(nc)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(x, params["embed"], cfg)
-    return logits, new_cache
+    return logits, {"groups": new_groups}

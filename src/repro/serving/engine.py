@@ -10,7 +10,7 @@ NOT ``train_step``").
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable
+from typing import Any, Callable, Dict
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +41,19 @@ def params_from_input(cu_ctx, weights_du: str) -> Any:
         return unflatten_tree(items)
 
 
+#: decode-cache leaves of the fp32 SSM and conv state; every other leaf is KV
+STATE_LEAVES = ("ssm", "conv_x", "conv_bc")
+
+
+def cache_bytes(cache: Any) -> Dict[str, int]:
+    """``kv_bytes`` and ``ssm_bytes`` (SSM and conv state) of a decode cache."""
+    out = {"kv_bytes": 0, "ssm_bytes": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        kind = "ssm_bytes" if path[-1].key in STATE_LEAVES else "kv_bytes"
+        out[kind] += leaf.size * leaf.dtype.itemsize
+    return out
+
+
 def make_serve_step(api: ModelApi) -> Callable:
     """serve_step(params, cache, tokens [B,1], pos) → (next_tokens, cache)."""
 
@@ -62,14 +75,15 @@ class DecodeEngine:
     """Minimal batched engine: static batch, greedy sampling; the
     pure-compute layer of a serve CU.
 
-    Its host side is traced: ``engine.init``, ``engine.prefill`` and
-    ``engine.generate`` spans (the tracing and compiling of the step land
-    under the first call as ``jit.*`` records), and an ``engine.steps``
-    counter of ``serve_step`` dispatches.
+    Its host side is traced: ``engine.init`` (with the cache's
+    ``kv_bytes`` and ``ssm_bytes``, also counted under those names),
+    ``engine.prefill`` and ``engine.generate`` spans (the tracing and
+    compiling of the step land under the first call as ``jit.*`` records),
+    and an ``engine.steps`` counter of ``serve_step`` dispatches.
     """
 
     def __init__(self, api: ModelApi, params: Any, batch: int, max_len: int):
-        with tracing.span("engine.init", batch=batch, max_len=max_len):
+        with tracing.span("engine.init", batch=batch, max_len=max_len) as sp:
             self.api = api
             self.params = params
             self.batch = batch
@@ -77,6 +91,10 @@ class DecodeEngine:
             self.cache = api.init_cache(batch, max_len)
             self._step = jax.jit(make_serve_step(api))
             self._pos = 0
+            sizes = cache_bytes(self.cache)
+            sp.attrs.update(sizes)
+            for name, n in sizes.items():
+                tracing.count(name, n)
 
     @classmethod
     def from_cu_context(

@@ -73,7 +73,7 @@ def test_decode_step(arch, rng):
     assert jnp.isfinite(jnp.asarray(logits2, jnp.float32)).all()
 
 
-@pytest.mark.parametrize("arch", ["mamba2-370m", "zamba2-1.2b", "h2o-danube-1.8b", "gemma3-1b"])
+@pytest.mark.parametrize("arch", ["mamba2-370m", "zamba2-7b", "h2o-danube-1.8b", "gemma3-1b"])
 def test_decode_matches_forward(arch, rng):
     """Prefill-vs-decode consistency: feeding tokens one-by-one through the
     cache must reproduce the teacher-forced logits."""

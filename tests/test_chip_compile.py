@@ -88,3 +88,38 @@ def test_gemma3_1b_serve_step_fits_one_v5e(one_chip):
     )
     assert mem.argument_size_in_bytes > 1.5e9  # the weights are all there
     assert total < V5E_HBM_BYTES, mem
+
+
+def test_zamba2_7b_cut_serve_step_fits_one_v5e(one_chip):
+    """The decode step at the benchmark's Zamba2 cut: published widths,
+    layers 0-12 (sites 6 and 11), bf16, batch 8 and 4096 slots."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.serving.engine import make_serve_step
+
+    full = get_config("zamba2-7b")
+    cut = dataclasses.replace(full, n_layers=13, pattern=full.layer_kinds()[:13])
+    api = build_model(cut)
+
+    def on_chip(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    cache = jax.tree.map(on_chip, jax.eval_shape(lambda: api.init_cache(8, 4096)))
+    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = (
+        jax.jit(make_serve_step(api)).lower(params, cache, tokens, pos).compile()
+    )
+    mem = compiled.memory_analysis()
+    total = (
+        mem.argument_size_in_bytes
+        + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+        - mem.alias_size_in_bytes
+    )
+    # 3.67 GB of weights and the 2.08 GB cache in, a new cache out
+    assert mem.argument_size_in_bytes > 5.7e9
+    assert total < V5E_HBM_BYTES, mem
