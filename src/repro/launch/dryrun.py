@@ -46,7 +46,7 @@ from ..distributed.sharding_rules import (
 )
 from ..models import build_model
 from ..optim import init_adamw
-from ..serving import make_serve_step
+from ..serving.engine import make_serve_step, split_cache
 from ..training import make_train_step
 from .hlo_analysis import analyze_hlo
 from .mesh import make_production_mesh, mesh_batch_axes
@@ -228,17 +228,18 @@ def run_cell(
             )
             c_spec = cache_specs(cache_shapes, cfg, ctx)
             c_ns = _ns_tree(mesh, c_spec)
-            cache_in = _with_shardings(cache_shapes, c_ns)
+            kv_in, state_in = split_cache(_with_shardings(cache_shapes, c_ns))
+            kv_ns, state_ns = split_cache(c_ns)
             serve_step = make_serve_step(api)
             tok_ns = batch_in["tokens"].sharding
             pos_in = _struct((), jnp.int32, NamedSharding(mesh, P()))
             jitted = jax.jit(
                 serve_step,
-                out_shardings=(tok_ns, c_ns),
-                donate_argnums=(1,),
+                out_shardings=(tok_ns, kv_ns, state_ns),
+                donate_argnums=(1, 2),
             )
             lowered = jitted.lower(
-                params_in, cache_in, batch_in["tokens"], pos_in
+                params_in, kv_in, state_in, batch_in["tokens"], pos_in
             )
 
         t_lower = time.time() - t0

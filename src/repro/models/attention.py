@@ -72,6 +72,67 @@ def gqa_attention(
     return out.reshape(b, sq, hq, d)
 
 
+def decode_gqa_attention(
+    q: jnp.ndarray,  # [B, 1, Hq, D]
+    ck: jnp.ndarray,  # [B, Sk, Hkv, D]: the cache's slots, read only
+    cv: jnp.ndarray,  # [B, Sk, Hkv, D]
+    k: jnp.ndarray,  # [B, 1, Hkv, D]: the token's own key and value
+    v: jnp.ndarray,  # [B, 1, Hkv, D]
+    seen: jnp.ndarray,  # [B, Sk] bool: the slots the token attends to
+    scale: Optional[float] = None,  # of the scores; default D**-0.5
+) -> jnp.ndarray:
+    """``gqa_attention`` of one token over the cache's ``seen`` slots and
+    its own key, without writing the key into the cache first: one fp32
+    softmax over both score sets, then p_cache·V_cache + p_token·v_token.
+    Returns [B, 1, Hq, D]."""
+    b, _, hq, d = q.shape
+    hkv = ck.shape[2]
+    q = q.reshape(b, 1, hkv, hq // hkv, d).astype(jnp.float32)
+    scale = d**-0.5 if scale is None else scale
+
+    def scores(keys):
+        return jnp.einsum("bqhgd,bkhd->bhgqk", q, keys.astype(jnp.float32)) * scale
+
+    old = jnp.where(seen[:, None, None, None, :], scores(ck), NEG_INF)
+    new = scores(k)
+    top = jnp.maximum(jnp.max(old, axis=-1, keepdims=True), new)
+    e_old, e_new = jnp.exp(old - top), jnp.exp(new - top)
+    total = jnp.sum(e_old, axis=-1, keepdims=True) + e_new
+
+    def weigh(e, values):
+        p = (e / total).astype(values.dtype)
+        return jnp.einsum(
+            "bhgqk,bkhd->bqhgd", p, values, preferred_element_type=jnp.float32
+        )
+
+    out = weigh(e_old, cv) + weigh(e_new, v)
+    return out.astype(cv.dtype).reshape(b, 1, hq, d)
+
+
+def _q8(t: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Per-token-per-head symmetric int8 and its fp32 scales."""
+    scale = jnp.max(jnp.abs(t.astype(jnp.float32)), axis=-1) / 127.0
+    scale = jnp.maximum(scale, 1e-8)
+    q = jnp.clip(
+        jnp.round(t.astype(jnp.float32) / scale[..., None]), -127, 127
+    ).astype(jnp.int8)
+    return q, scale
+
+
+def _dq8(t: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
+    return (t.astype(jnp.float32) * scale.astype(jnp.float32)[..., None]).astype(dtype)
+
+
+def write_token(
+    cache: jnp.ndarray, token: jnp.ndarray, cache_index: jnp.ndarray, axis: int
+) -> jnp.ndarray:
+    """``cache`` with ``token`` (size 1 along ``axis``, the slot axis) at slot
+    ``cache_index % slots``: SWA caches are ring buffers of window length,
+    full-length caches hit the identity case of the same formula."""
+    slot = cache_index % cache.shape[axis]
+    return jax.lax.dynamic_update_slice_in_dim(cache, token, slot, axis=axis)
+
+
 # ------------------------------------------------------------ block apply
 def attention_block(
     params: Dict,
@@ -91,8 +152,10 @@ def attention_block(
 
     x is [B, S, d_in] for the projections' input width.  ``scale`` of the
     scores (default head_dim**-0.5) may be set on the reference path only.
-    With ``cache``/``cache_index``: single-token decode — x is [B, 1, d_in],
-    the KV cache is updated in place (functionally) at ``cache_index``.
+    With ``cache``/``cache_index``: single-token decode — x is [B, 1, d_in]
+    and the returned cache is the token's own K and V, [B, 1, Hkv, D] (int8
+    and its scales on a quantized cache), which ``write_token`` puts at
+    ``cache_index`` in the caller's cache.
     """
     assert scale is None or impl == "ref", f"impl {impl!r} scales by head_dim**-0.5"
     b, s, _ = x.shape
@@ -104,78 +167,53 @@ def attention_block(
     q = apply_rope(q, positions, theta)
     k = apply_rope(k, positions, theta)
 
-    new_cache = None
+    new_kv = None
     if cache is not None:
-        assert cache_index is not None
-        # decode: write k/v at cache_index (ring buffer — SWA caches are
-        # allocated at window length, so the write index wraps; full-length
-        # caches hit the identity case of the same formula)
+        assert cache_index is not None and s == 1, "the cache path decodes one token"
+        # the cache is read, never written here: the layer hands back the
+        # token's K and V (as the cache holds them) for the caller to write
+        # at slot cache_index % slots in place (``write_token``)
         s_cache = cache["k"].shape[1]
-        write_idx = cache_index % s_cache
-        quantized = cache["k"].dtype == jnp.int8
-        if quantized:
-            # per-token-per-head symmetric int8 (scales stored alongside)
-            def q8(t):
-                scale = jnp.max(jnp.abs(t.astype(jnp.float32)), axis=-1) / 127.0
-                scale = jnp.maximum(scale, 1e-8)
-                q = jnp.clip(
-                    jnp.round(t.astype(jnp.float32) / scale[..., None]),
-                    -127, 127,
-                ).astype(jnp.int8)
-                return q, scale
-
-            k8, k_s = q8(k)
-            v8, v_s = q8(v)
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k8, write_idx, axis=1
-            )
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v8, write_idx, axis=1
-            )
-            cks = jax.lax.dynamic_update_slice_in_dim(
-                cache["k_scale"], k_s.astype(cache["k_scale"].dtype), write_idx, axis=1
-            )
-            cvs = jax.lax.dynamic_update_slice_in_dim(
-                cache["v_scale"], v_s.astype(cache["v_scale"].dtype), write_idx, axis=1
-            )
-            new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
+        if cache["k"].dtype == jnp.int8:
+            k8, k_s = _q8(k)
+            v8, v_s = _q8(v)
+            new_kv = {
+                "k": k8,
+                "v": v8,
+                "k_scale": k_s.astype(cache["k_scale"].dtype),
+                "v_scale": v_s.astype(cache["v_scale"].dtype),
+            }
             cdt = dt(cfg.compute_dtype)
-            ck = (ck.astype(jnp.float32) * cks.astype(jnp.float32)[..., None]).astype(cdt)
-            cv = (cv.astype(jnp.float32) * cvs.astype(jnp.float32)[..., None]).astype(cdt)
+            ck = _dq8(cache["k"], cache["k_scale"], cdt)
+            cv = _dq8(cache["v"], cache["v_scale"], cdt)
+            k = _dq8(k8, new_kv["k_scale"], cdt)
+            v = _dq8(v8, new_kv["v_scale"], cdt)
         else:
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), write_idx, axis=1
-            )
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), write_idx, axis=1
-            )
-            new_cache = {"k": ck, "v": cv}
-        sk = ck.shape[1]
-        # slot j holds absolute position pos - ((pos - j) mod s_cache);
-        # never-written slots resolve to negative positions → masked.
-        slots = jnp.arange(sk)[None, :]
-        pos_now = cache_index + s - 1
-        positions_k = pos_now - jnp.mod(pos_now - slots, s_cache)
-        positions_k = jnp.broadcast_to(positions_k, (b, sk)).astype(jnp.int32)
-        kv_valid = positions_k >= 0
-        if impl == "flash" and s == 1:
+            new_kv = {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
+            ck, cv = cache["k"], cache["v"]
+            k, v = new_kv["k"], new_kv["v"]
+        if impl == "flash":
             from ..kernels.decode_attention import ops as dec_ops
 
             out = dec_ops.decode_attention(
-                q, ck, cv, positions[:, 0], window=window
+                q,
+                write_token(ck, k, cache_index, axis=1),
+                write_token(cv, v, cache_index, axis=1),
+                positions[:, 0],
+                window=window,
             )
         else:
-            out = gqa_attention(
-                q,
-                ck,
-                cv,
-                positions,
-                positions_k,
-                causal=causal,
-                window=window,
-                kv_valid=kv_valid,
-                scale=scale,
-            )
+            # slot j holds absolute position pos - ((pos - j) mod s_cache);
+            # never-written slots resolve to negative positions, and slot
+            # pos % s_cache holds nothing or the position this token
+            # replaces: both masked
+            slots = jnp.arange(s_cache)[None, :]
+            positions_k = cache_index - jnp.mod(cache_index - slots, s_cache)
+            seen = (positions_k >= 0) & (slots != cache_index % s_cache)
+            if window is not None:
+                seen &= positions[:, :1] - positions_k < window
+            seen = jnp.broadcast_to(seen, (b, s_cache))
+            out = decode_gqa_attention(q, ck, cv, k, v, seen, scale=scale)
     else:
         if impl == "flash":
             from ..kernels.flash_attention import ops as fa_ops
@@ -195,7 +233,7 @@ def attention_block(
                 scale=scale,
             )
     out = dense(out.reshape(b, s, cfg.n_heads * hd), params["o"])
-    return out, new_cache
+    return out, new_kv
 
 
 def cross_attention_block(

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .attention import attention_block, init_attention
+from .attention import attention_block, init_attention, write_token
 from .layers import (
     chunked_cross_entropy,
     dense,
@@ -341,6 +341,10 @@ def loss_fn(
 
 
 # ----------------------------------------------------------------- decode
+#: decode-cache leaves of the fp32 SSM and conv state; every other leaf is KV
+STATE_LEAVES = ("ssm", "conv_x", "conv_bc")
+
+
 def _ssm_cache(cfg: ModelConfig, batch: int) -> Dict:
     dims = mamba_dims(cfg)
     gn2 = 2 * dims["n_groups"] * dims["d_state"]
@@ -419,7 +423,7 @@ def decode_step(
         pos_index.astype(jnp.int32)[None, None], (b, 1)
     )
     shared = params.get("shared")
-    new_groups: Dict[str, Any] = {}
+    groups: Dict[str, Any] = {}
 
     for first, period, _ in cfg.layer_runs():
         def body(x, xs, first=first, period=period):
@@ -440,16 +444,18 @@ def decode_step(
             )
             return x, nc
 
-        x, nc = jax.lax.scan(
-            body,
-            x,
-            (
-                _run(params["groups"], first, period),
-                _run(cache["groups"], first, period),
-            ),
-        )
-        new_groups.update(nc)
+        run = _run(cache["groups"], first, period)
+        x, nc = jax.lax.scan(body, x, (_run(params["groups"], first, period), run))
+        # the scan reads each layer's KV in place and hands back only the
+        # token's; one write per KV leaf puts the run's tokens in the cache
+        for key, leaves in run.items():
+            groups[key] = {
+                name: nc[key][name]
+                if name in STATE_LEAVES
+                else write_token(leaf, nc[key][name], pos_index, axis=2)
+                for name, leaf in leaves.items()
+            }
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(x, params["embed"], cfg)
-    return logits, {"groups": new_groups}
+    return logits, {"groups": groups}

@@ -14,7 +14,13 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .attention import attention_block, cross_attention_block, init_attention, precompute_cross_kv
+from .attention import (
+    attention_block,
+    cross_attention_block,
+    init_attention,
+    precompute_cross_kv,
+    write_token,
+)
 from .layers import dt, embed, init_embedding, init_mlp, init_rmsnorm, mlp, rms_norm, unembed
 
 
@@ -222,7 +228,7 @@ def decode_step(
         x = x + mlp(h, lp["mlp"], cfg)
         return x, (new_kv["k"], new_kv["v"])
 
-    x, (nk, nv) = jax.lax.scan(
+    x, (tk, tv) = jax.lax.scan(
         body,
         x,
         (
@@ -235,4 +241,8 @@ def decode_step(
     )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(x, params["embed"], cfg)
-    return logits, {"self": {"k": nk, "v": nv}, "cross": cache["cross"]}
+    self_kv = {
+        "k": write_token(cache["self"]["k"], tk, pos_index, axis=2),
+        "v": write_token(cache["self"]["v"], tv, pos_index, axis=2),
+    }
+    return logits, {"self": self_kv, "cross": cache["cross"]}

@@ -10,7 +10,7 @@ NOT ``train_step``").
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from .. import tracing
 from ..checkpoint import decode_array, unflatten_tree
 from ..models.registry import ModelApi
+from ..models.transformer import STATE_LEAVES
 
 
 def params_from_input(cu_ctx, weights_du: str) -> Any:
@@ -41,28 +42,54 @@ def params_from_input(cu_ctx, weights_du: str) -> Any:
         return unflatten_tree(items)
 
 
-#: decode-cache leaves of the fp32 SSM and conv state; every other leaf is KV
-STATE_LEAVES = ("ssm", "conv_x", "conv_bc")
+def split_cache(cache: Dict) -> Tuple[Dict, Dict]:
+    """(kv, state): a decode cache's KV leaves and its SSM and conv state
+    leaves (``STATE_LEAVES``), each in the cache's own nesting."""
+    kv, state = {}, {}
+    for key, sub in cache.items():
+        if isinstance(sub, dict):
+            kv[key], state[key] = split_cache(sub)
+        else:
+            (state if key in STATE_LEAVES else kv)[key] = sub
+    return kv, state
+
+
+def join_cache(kv: Dict, state: Dict) -> Dict:
+    """The decode cache ``split_cache`` took apart."""
+    out = dict(kv)
+    for key, sub in state.items():
+        out[key] = join_cache(out.get(key, {}), sub) if isinstance(sub, dict) else sub
+    return out
+
+
+def tree_bytes(tree: Any) -> int:
+    return sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(tree))
 
 
 def cache_bytes(cache: Any) -> Dict[str, int]:
     """``kv_bytes`` and ``ssm_bytes`` (SSM and conv state) of a decode cache."""
-    out = {"kv_bytes": 0, "ssm_bytes": 0}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-        kind = "ssm_bytes" if path[-1].key in STATE_LEAVES else "kv_bytes"
-        out[kind] += leaf.size * leaf.dtype.itemsize
-    return out
+    kv, state = split_cache(cache)
+    return {"kv_bytes": tree_bytes(kv), "ssm_bytes": tree_bytes(state)}
 
 
 def make_serve_step(api: ModelApi) -> Callable:
-    """serve_step(params, cache, tokens [B,1], pos) → (next_tokens, cache)."""
+    """serve_step(params, kv, state, tokens [B,1], pos) → (next_tokens, kv,
+    state), the decode cache split by ``split_cache`` so that its KV alone
+    can be donated: the step writes one token per KV leaf in place, and
+    rewrites the state whole."""
 
-    def serve_step(params, cache, tokens, pos_index):
+    def serve_step(params, kv, state, tokens, pos_index):
+        cache = join_cache(kv, state)
         logits, cache = api.decode_step(params, cache, tokens, pos_index)
         next_tokens = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        return next_tokens[:, None], cache
+        return (next_tokens[:, None], *split_cache(cache))
 
     return serve_step
+
+
+def jit_serve_step(api: ModelApi) -> Callable:
+    """``make_serve_step`` jitted with the KV argument donated."""
+    return jax.jit(make_serve_step(api), donate_argnums=(1,))
 
 
 @dataclasses.dataclass
@@ -75,8 +102,12 @@ class DecodeEngine:
     """Minimal batched engine: static batch, greedy sampling; the
     pure-compute layer of a serve CU.
 
+    Its step is ``jit_serve_step``: each writes its tokens into the
+    cache's KV in place.
+
     Its host side is traced: ``engine.init`` (with the cache's
-    ``kv_bytes`` and ``ssm_bytes``, also counted under those names),
+    ``kv_bytes``, ``ssm_bytes`` and the ``donated_bytes`` of its KV, also
+    counted under those names),
     ``engine.prefill`` and ``engine.generate`` spans (the tracing and
     compiling of the step land under the first call as ``jit.*`` records),
     and an ``engine.steps`` counter of ``serve_step`` dispatches.
@@ -88,13 +119,23 @@ class DecodeEngine:
             self.params = params
             self.batch = batch
             self.max_len = max_len
-            self.cache = api.init_cache(batch, max_len)
-            self._step = jax.jit(make_serve_step(api))
+            self._kv, self._state = split_cache(api.init_cache(batch, max_len))
+            self._step = jit_serve_step(api)
             self._pos = 0
-            sizes = cache_bytes(self.cache)
+            kv = tree_bytes(self._kv)  # all of it donated to each step
+            sizes = {
+                "kv_bytes": kv,
+                "ssm_bytes": tree_bytes(self._state),
+                "donated_bytes": kv,
+            }
             sp.attrs.update(sizes)
             for name, n in sizes.items():
                 tracing.count(name, n)
+
+    @property
+    def cache(self) -> Dict:
+        """The whole decode cache, KV and state."""
+        return join_cache(self._kv, self._state)
 
     @classmethod
     def from_cu_context(
@@ -115,8 +156,9 @@ class DecodeEngine:
         assert b == self.batch
         last = None
         for i in range(s):
-            last, self.cache = self._step(
-                self.params, self.cache, tokens[:, i : i + 1], jnp.int32(self._pos)
+            last, self._kv, self._state = self._step(
+                self.params, self._kv, self._state, tokens[:, i : i + 1],
+                jnp.int32(self._pos),
             )
             self._pos += 1
         tracing.count("engine.steps", s)
@@ -128,8 +170,8 @@ class DecodeEngine:
             cur = self._feed(tokens)
             out = [cur]
             for _ in range(max_new_tokens - 1):
-                cur, self.cache = self._step(
-                    self.params, self.cache, cur, jnp.int32(self._pos)
+                cur, self._kv, self._state = self._step(
+                    self.params, self._kv, self._state, cur, jnp.int32(self._pos)
                 )
                 self._pos += 1
                 out.append(cur)
