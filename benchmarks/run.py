@@ -1,4 +1,4 @@
-"""Benchmark harness — one bench per paper table/figure + roofline.
+"""Benchmark harness — one bench per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV (one row per measurement), and with
 ``--json PATH`` additionally writes the same rows machine-readably so CI
@@ -31,7 +31,6 @@ can archive a perf trajectory artifact per run.
                        uncontended vs quota-fair vs unquota'd flood, plus
                        the tenant-aware-eviction pinned-set claim
   bench_cost_model   — §6.1 calculus vs oracle + replication degree
-  bench_roofline     — assignment §Roofline terms from dry-run artifacts
 """
 
 import argparse
@@ -71,7 +70,6 @@ def main() -> None:
         bench_multitenant,
         bench_placement,
         bench_replication,
-        bench_roofline,
         bench_scale,
         bench_staging,
         bench_store,
@@ -92,7 +90,6 @@ def main() -> None:
         "store": lambda: bench_store.run(),
         "multitenant": lambda: bench_multitenant.run(quick=args.quick),
         "cost_model": lambda: bench_cost_model.run(),
-        "roofline": lambda: bench_roofline.run(),
     }
     if args.only and args.only not in benches:
         print(

@@ -2,7 +2,6 @@ from .base import (
     EncDecConfig,
     ModelConfig,
     MoEConfig,
-    SHAPES,
     SMOKE_SHAPE,
     ShapeConfig,
     SharedBlockConfig,
@@ -10,10 +9,10 @@ from .base import (
     VLMConfig,
     reduced,
 )
-from .registry import ARCHS, cell_is_applicable, get_config, get_shape, list_archs
+from .registry import ARCHS, get_config, list_archs
 
 __all__ = [
-    "EncDecConfig", "ModelConfig", "MoEConfig", "SHAPES", "SMOKE_SHAPE",
+    "EncDecConfig", "ModelConfig", "MoEConfig", "SMOKE_SHAPE",
     "ShapeConfig", "SharedBlockConfig", "SSMConfig", "VLMConfig", "reduced",
-    "ARCHS", "cell_is_applicable", "get_config", "get_shape", "list_archs",
+    "ARCHS", "get_config", "list_archs",
 ]

@@ -1,12 +1,11 @@
-"""Config system: architecture + shape + run configuration.
+"""Config system: architecture + shape configuration.
 
-Every assigned architecture is a :class:`ModelConfig` in its own module
-(``repro.configs.<arch>``); shapes are :class:`ShapeConfig` (assignment's
-train_4k / prefill_32k / decode_32k / long_500k).  ``reduced()`` derives the
-CPU-smoke-test variant of any config (same family/block pattern, tiny
-dims).
+Every architecture is a :class:`ModelConfig` in its own module
+(``repro.configs.<arch>``); a :class:`ShapeConfig` is a batch of inputs
+(``SMOKE_SHAPE`` for tests).  ``reduced()`` derives the CPU-smoke-test
+variant of any config (same family/block pattern, tiny dims).
 
-This module is dependency-light (no jax import) so launchers can read
+This module is dependency-light (no jax import) so callers can read
 configs before touching jax.
 """
 
@@ -14,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,29 +115,12 @@ class ModelConfig:
     vlm: Optional[VLMConfig] = None
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
-    #: does any block attend with an unbounded (full) window?
-    #: (drives the long_500k applicability rule)
     source: str = ""
 
     # ------------------------------------------------------------ derived
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
-
-    @property
-    def is_attention_free(self) -> bool:
-        return all(p == "mamba" for p in self.pattern)
-
-    @property
-    def is_pure_full_attention(self) -> bool:
-        """True if every attention block is full/unwindowed (assignment's
-        long_500k skip rule)."""
-        att = {p for p in self.pattern if p != "mamba"}
-        return bool(att) and att <= {"attn", "global", "moe"}
-
-    @property
-    def supports_long_context(self) -> bool:
-        return not self.is_pure_full_attention
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """The concrete per-layer kinds for the full depth."""
@@ -255,19 +237,6 @@ class ShapeConfig:
     seq_len: int
     global_batch: int
     kind: str  # "train" | "prefill" | "decode"
-
-    @property
-    def tokens(self) -> int:
-        return self.seq_len * self.global_batch
-
-
-#: the assignment's four shapes (shared by every LM arch)
-SHAPES: Dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
-}
 
 
 def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:

@@ -4,7 +4,7 @@ from __future__ import annotations
 
 from typing import Dict, List
 
-from .base import ModelConfig, SHAPES, ShapeConfig, reduced
+from .base import ModelConfig, reduced
 
 from .granite_34b import CONFIG as granite_34b
 from .gemma3_12b import CONFIG as gemma3_12b
@@ -42,18 +42,5 @@ def get_config(name: str) -> ModelConfig:
     return ARCHS[name]
 
 
-def get_shape(name: str) -> ShapeConfig:
-    if name not in SHAPES:
-        raise KeyError(f"unknown shape {name!r}; known: {sorted(SHAPES)}")
-    return SHAPES[name]
-
-
 def list_archs() -> List[str]:
     return sorted(ARCHS)
-
-
-def cell_is_applicable(cfg: ModelConfig, shape: ShapeConfig) -> bool:
-    """The assignment's skip rule: long_500k only for sub-quadratic archs."""
-    if shape.name == "long_500k":
-        return cfg.supports_long_context
-    return True

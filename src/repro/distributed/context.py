@@ -1,7 +1,8 @@
 """Distribution context: how model code learns about the mesh.
 
-Model code stays mesh-agnostic; launchers activate a :class:`DistContext`
-(mesh + axis roles) around tracing.  Inside model code:
+Model code stays mesh-agnostic; a caller that shards the model activates a
+:class:`DistContext` (mesh + axis roles) around tracing.  No caller in the
+tree does yet, so on every path that runs ``constrain`` is a no-op.  Inside model code:
 
   * ``constrain(x, "residual")`` — applies a named activation sharding
     constraint if a context is active (no-op otherwise, so CPU smoke tests

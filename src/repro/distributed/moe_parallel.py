@@ -2,8 +2,7 @@
 MoE body when a distribution context is active.
 
 Train/prefill (S divisible by the model axis) → explicit shard_map with
-all-to-all over the model axis (collective bytes visible in the dry-run
-HLO).  Decode (S == 1) or no mesh → the local gather/scatter path; GSPMD
+all-to-all over the model axis.  Decode (S == 1) or no mesh → the local gather/scatter path; GSPMD
 partitions it automatically (the tensors are tiny at decode).
 """
 

@@ -13,7 +13,7 @@ The layout (per DESIGN.md §4):
     carries the parallelism).
 
 Rules are *path-based* over pytrees of ShapeDtypeStructs, so they apply
-identically to live arrays and to dry-run eval_shape trees.
+identically to live arrays and to ``jax.eval_shape`` trees.
 """
 
 from __future__ import annotations
@@ -165,7 +165,7 @@ def cache_specs(cache_shapes: Any, cfg: ModelConfig, ctx: DistContext) -> Any:
     """KV/SSM cache sharding for decode.
 
     KV: [(L,)? B, S, Hkv, D] — batch over DP; the S dim over model (and
-    over DP too when the batch doesn't divide, e.g. long_500k's B=1).
+    over DP too when the batch doesn't divide, e.g. one long sequence, B=1).
     SSM state: [(L,)? B, H, P, N] — batch over DP, heads over model.
     """
     bt = ctx.batch_size_total

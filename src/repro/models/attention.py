@@ -1,12 +1,11 @@
 """Attention: GQA with causal / sliding-window / bidirectional / cross modes,
 prefill and single-token decode paths.
 
-The jnp implementation here is the *reference semantics*; the Pallas
-flash-attention kernels in ``repro.kernels`` implement the same math with
-VMEM tiling and are validated against this module (tests sweep shapes &
-dtypes).  Model code selects the implementation via ``impl=`` — dry-runs use
-"ref" (XLA fuses it; keeps HLO compact at 512 devices), TPU runs would use
-"flash".
+This jnp code is the one path the model runs, and XLA fuses it.  The
+Pallas kernels in ``repro.kernels`` implement the same math with VMEM
+tiling; ``tests/test_kernels.py`` checks ``flash_attention`` against
+``gqa_attention`` and ``decode_attention`` against
+``decode_gqa_attention``.
 """
 
 from __future__ import annotations
@@ -109,6 +108,26 @@ def decode_gqa_attention(
     return out.astype(cv.dtype).reshape(b, 1, hq, d)
 
 
+def seen_slots(
+    positions: jnp.ndarray,  # [B, 1]: the token's position
+    cache_index: jnp.ndarray,  # scalar: the step's write position
+    s_cache: int,
+    window: Optional[int] = None,
+) -> jnp.ndarray:
+    """[B, s_cache] bool: the cache slots a decoded token attends to.
+
+    Slot j holds absolute position pos - ((pos - j) mod s_cache);
+    never-written slots resolve to negative positions, and slot
+    pos % s_cache holds nothing or the position this token replaces: both
+    masked."""
+    slots = jnp.arange(s_cache)[None, :]
+    positions_k = cache_index - jnp.mod(cache_index - slots, s_cache)
+    seen = (positions_k >= 0) & (slots != cache_index % s_cache)
+    if window is not None:
+        seen &= positions[:, :1] - positions_k < window
+    return jnp.broadcast_to(seen, (positions.shape[0], s_cache))
+
+
 def _q8(t: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Per-token-per-head symmetric int8 and its fp32 scales."""
     scale = jnp.max(jnp.abs(t.astype(jnp.float32)), axis=-1) / 127.0
@@ -145,19 +164,17 @@ def attention_block(
     rope_theta: Optional[float] = None,
     cache: Optional[Dict] = None,
     cache_index: Optional[jnp.ndarray] = None,
-    impl: str = "ref",
     scale: Optional[float] = None,
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """Full attention sub-block: qkv proj → rope → attention → out proj.
 
-    x is [B, S, d_in] for the projections' input width.  ``scale`` of the
-    scores (default head_dim**-0.5) may be set on the reference path only.
+    x is [B, S, d_in] for the projections' input width; ``scale`` of the
+    scores defaults to head_dim**-0.5.
     With ``cache``/``cache_index``: single-token decode — x is [B, 1, d_in]
     and the returned cache is the token's own K and V, [B, 1, Hkv, D] (int8
     and its scales on a quantized cache), which ``write_token`` puts at
     ``cache_index`` in the caller's cache.
     """
-    assert scale is None or impl == "ref", f"impl {impl!r} scales by head_dim**-0.5"
     b, s, _ = x.shape
     hd = cfg.head_dim_
     theta = rope_theta if rope_theta is not None else cfg.rope_theta
@@ -192,46 +209,13 @@ def attention_block(
             new_kv = {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
             ck, cv = cache["k"], cache["v"]
             k, v = new_kv["k"], new_kv["v"]
-        if impl == "flash":
-            from ..kernels.decode_attention import ops as dec_ops
-
-            out = dec_ops.decode_attention(
-                q,
-                write_token(ck, k, cache_index, axis=1),
-                write_token(cv, v, cache_index, axis=1),
-                positions[:, 0],
-                window=window,
-            )
-        else:
-            # slot j holds absolute position pos - ((pos - j) mod s_cache);
-            # never-written slots resolve to negative positions, and slot
-            # pos % s_cache holds nothing or the position this token
-            # replaces: both masked
-            slots = jnp.arange(s_cache)[None, :]
-            positions_k = cache_index - jnp.mod(cache_index - slots, s_cache)
-            seen = (positions_k >= 0) & (slots != cache_index % s_cache)
-            if window is not None:
-                seen &= positions[:, :1] - positions_k < window
-            seen = jnp.broadcast_to(seen, (b, s_cache))
-            out = decode_gqa_attention(q, ck, cv, k, v, seen, scale=scale)
+        seen = seen_slots(positions, cache_index, s_cache, window)
+        out = decode_gqa_attention(q, ck, cv, k, v, seen, scale=scale)
     else:
-        if impl == "flash":
-            from ..kernels.flash_attention import ops as fa_ops
-
-            out = fa_ops.flash_attention(
-                q, k, v, causal=causal, window=window
-            )
-        elif impl == "blocked":
-            from .blocked_attention import blocked_attention
-
-            out = blocked_attention(
-                q, k, v, positions, positions, causal, window, 1024, False
-            )
-        else:
-            out = gqa_attention(
-                q, k, v, positions, positions, causal=causal, window=window,
-                scale=scale,
-            )
+        out = gqa_attention(
+            q, k, v, positions, positions, causal=causal, window=window,
+            scale=scale,
+        )
     out = dense(out.reshape(b, s, cfg.n_heads * hd), params["o"])
     return out, new_kv
 
@@ -241,7 +225,6 @@ def cross_attention_block(
     x: jnp.ndarray,  # [B, Sq, d]
     enc_kv: Tuple[jnp.ndarray, jnp.ndarray],  # precomputed K,V [B, Sk, Hkv, D]
     cfg: ModelConfig,
-    impl: str = "ref",
 ) -> jnp.ndarray:
     """Encoder-decoder cross attention (whisper); enc K/V precomputed once."""
     b, s, _ = x.shape
@@ -251,12 +234,7 @@ def cross_attention_block(
     sk = k.shape[1]
     pos_q = jnp.zeros((b, s), dtype=jnp.int32)
     pos_k = jnp.zeros((b, sk), dtype=jnp.int32)
-    if impl == "blocked" and s > 1:
-        from .blocked_attention import blocked_attention
-
-        out = blocked_attention(q, k, v, pos_q, pos_k, False, None, 1024, False)
-    else:
-        out = gqa_attention(q, k, v, pos_q, pos_k, causal=False, window=None)
+    out = gqa_attention(q, k, v, pos_q, pos_k, causal=False, window=None)
     return dense(out.reshape(b, s, cfg.n_heads * hd), params["o"])
 
 

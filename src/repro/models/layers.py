@@ -5,8 +5,8 @@ Conventions:
     pure apply function;
   * activations compute in ``cfg.compute_dtype`` (bf16 by default), softmax
     and norm statistics in fp32;
-  * init functions are cheap and `jax.eval_shape`-safe (dry-runs never
-    materialize full-size weights).
+  * init functions are cheap and `jax.eval_shape`-safe (shape checks and
+    lowering never materialize full-size weights).
 """
 
 from __future__ import annotations

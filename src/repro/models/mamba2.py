@@ -2,9 +2,9 @@
 
 Implements the chunked SSD algorithm of arXiv:2405.21060: within a chunk the
 computation is a masked (decay-weighted) attention-like quadratic form; the
-state is carried across chunks with a linear recurrence.  This module is the
-*reference semantics*; ``repro.kernels.ssd_scan`` provides the Pallas
-TPU kernel for the intra-chunk part, validated against :func:`ssd_chunked`.
+state is carried across chunks with a linear recurrence.  This jnp code is
+the one path the model runs; ``repro.kernels.ssd_scan`` is a Pallas TPU
+kernel of the same math, validated against its own reference.
 
 Decode is O(1) per token: a [B, H, P, N] state and a small conv cache.
 """
@@ -171,7 +171,6 @@ def mamba_block(
     u: jnp.ndarray,  # [B, S, d_model]
     cfg: ModelConfig,
     initial_state: Optional[jnp.ndarray] = None,
-    impl: str = "ref",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full Mamba2 mixer; returns (out [B,S,d_model], final_state)."""
     dims = mamba_dims(cfg)
@@ -198,16 +197,9 @@ def mamba_block(
     A = -jnp.exp(params["A_log"])  # [H]
     dA = dt_ * A[None, None, :]
     xdt = x.astype(jnp.float32) * dt_[..., None]
-    if impl == "ssd_kernel":
-        from ..kernels.ssd_scan import ops as ssd_ops
-
-        y, final_state = ssd_ops.ssd(
-            xdt, dA, B_h, C_h, chunk=_ssm(cfg).chunk, initial_state=initial_state
-        )
-    else:
-        y, final_state = ssd_chunked(
-            xdt, dA, B_h, C_h, chunk=min(_ssm(cfg).chunk, s), initial_state=initial_state
-        )
+    y, final_state = ssd_chunked(
+        xdt, dA, B_h, C_h, chunk=min(_ssm(cfg).chunk, s), initial_state=initial_state
+    )
     y = y + params["D"][None, None, :, None] * x.astype(jnp.float32)
     y = y.reshape(b, s, dims["d_inner"]).astype(u.dtype)
     # gated RMSNorm over groups of d_inner / n_groups channels
@@ -241,8 +233,7 @@ def mamba_decode_step(
     cache: Dict,  # {"ssm": [B,H,P,N], "conv": [B,W-1,Ch]} — this layer's slice
     cfg: ModelConfig,
 ) -> Tuple[jnp.ndarray, Dict]:
-    """O(1) decode: constant-size state, no KV growth (the reason this arch
-    family runs the long_500k cell)."""
+    """O(1) decode: constant-size state, no KV growth."""
     dims = mamba_dims(cfg)
     b = u.shape[0]
     h, p, n, g = (

@@ -6,14 +6,12 @@ Two execution paths with identical routing math:
   * **local** — gather/scatter dispatch on one device (smoke tests, decode,
     and the per-device body of the EP path).  Dispatch is sort-based (no
     [T, E, C] one-hot einsums — those inflate HLO FLOPs by orders of
-    magnitude and would poison the roofline's MODEL_FLOPS/HLO_FLOPs ratio).
+    magnitude).
   * **expert-parallel** — ``jax.shard_map`` over the (data, model) mesh:
     tokens are locally dispatched into per-expert capacity buffers, an
     all-to-all over the *model* axis moves them to their expert's shard,
     expert FFNs run as blocked einsums, and a reverse all-to-all brings
-    results home.  This is the production EP pattern; the all-to-all bytes
-    are visible in the dry-run HLO and accounted in the collective roofline
-    term.
+    results home.  This is the production EP pattern.
 
 Experts whose count does not divide the model-axis size are padded with
 never-routed dummy experts (router logits pinned to -inf).

@@ -2,11 +2,11 @@
 
 ``build_model(cfg)`` returns a :class:`ModelApi` whose members are plain
 functions suitable for ``jax.jit`` / ``jax.eval_shape`` — init never
-allocates under ``eval_shape``, so dry-runs stay allocation-free.
+allocates under ``eval_shape``, so shape checks stay allocation-free.
 
-``batch_spec`` describes the logical model inputs per assignment shape
-(train / prefill / decode); the launcher turns these into sharded
-``ShapeDtypeStruct``s.
+``batch_spec`` describes the logical model inputs of a
+:class:`ShapeConfig` (train / prefill / decode); ``make_fake_batch`` fills
+them with random data.
 """
 
 from __future__ import annotations
@@ -61,39 +61,26 @@ def _lm_batch_spec(cfg: ModelConfig, shape: ShapeConfig):
     return spec
 
 
-def build_model(
-    cfg: ModelConfig,
-    ep: int = 1,
-    impl: str = "ref",
-    ep_axis: Optional[str] = None,
-) -> ModelApi:
+def build_model(cfg: ModelConfig, ep: int = 1) -> ModelApi:
+    """The model's functions with ``cfg`` bound; ``ep`` pads the MoE
+    experts to a multiple of the expert-parallel axis size."""
     if cfg.family == "encdec":
         return ModelApi(
             cfg=cfg,
             init=functools.partial(whisper.init_encdec, cfg=cfg, ep=ep),
-            forward=functools.partial(whisper.forward, cfg=cfg, impl=impl),
-            loss_fn=functools.partial(
-                whisper.loss_fn, cfg=cfg, impl=impl, ep_axis=ep_axis
-            ),
+            forward=functools.partial(whisper.forward, cfg=cfg),
+            loss_fn=functools.partial(whisper.loss_fn, cfg=cfg),
             init_cache=functools.partial(whisper.init_encdec_cache, cfg),
-            decode_step=functools.partial(
-                whisper.decode_step, cfg=cfg, impl=impl, ep_axis=ep_axis
-            ),
+            decode_step=functools.partial(whisper.decode_step, cfg=cfg),
             batch_spec=functools.partial(_lm_batch_spec, cfg),
         )
     return ModelApi(
         cfg=cfg,
         init=functools.partial(transformer.init_lm, cfg=cfg, ep=ep),
-        forward=functools.partial(
-            transformer.forward, cfg=cfg, impl=impl, ep_axis=ep_axis
-        ),
-        loss_fn=functools.partial(
-            transformer.loss_fn, cfg=cfg, impl=impl, ep_axis=ep_axis
-        ),
+        forward=functools.partial(transformer.forward, cfg=cfg),
+        loss_fn=functools.partial(transformer.loss_fn, cfg=cfg),
         init_cache=functools.partial(transformer.init_lm_cache, cfg),
-        decode_step=functools.partial(
-            transformer.decode_step, cfg=cfg, impl=impl, ep_axis=ep_axis
-        ),
+        decode_step=functools.partial(transformer.decode_step, cfg=cfg),
         batch_spec=functools.partial(_lm_batch_spec, cfg),
     )
 

@@ -4,8 +4,8 @@ families.
 Depth is organized as the config's runs (``ModelConfig.layer_runs``): each
 run repeats a period of layer kinds and is executed with ``lax.scan`` over
 per-layer parameters stacked on a leading axis — one period body in the HLO
-per run regardless of depth, which keeps 512-device SPMD compiles tractable
-and is also what makes per-layer remat policies cheap.  A cycled pattern is
+per run regardless of depth, which keeps compiles small and is also what
+makes per-layer remat policies cheap.  A cycled pattern is
 one run; an explicit layer list (zamba2) scans each repeated stretch.
 
 The parameters and the cache of the layer that opens a run's period at
@@ -125,7 +125,6 @@ def shared_block(
     e0: jnp.ndarray,
     positions: jnp.ndarray,
     cfg: ModelConfig,
-    impl: str = "ref",
     cache: Optional[Dict] = None,
     cache_index: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
@@ -145,7 +144,6 @@ def shared_block(
             rope_theta=cfg.rope_theta,
             cache=cache,
             cache_index=cache_index,
-            impl=impl,
             scale=(cfg.head_dim_ / 2) ** -0.5,
         )
         h = rms_norm(a, sp["ln2"], cfg.norm_eps)
@@ -161,8 +159,6 @@ def apply_block(
     cfg: ModelConfig,
     shared: Optional[Dict] = None,
     e0: Optional[jnp.ndarray] = None,
-    impl: str = "ref",
-    ep_axis: Optional[str] = None,
     cache: Optional[Dict] = None,
     cache_index: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[Dict]]:
@@ -172,14 +168,14 @@ def apply_block(
     if kind in ("mamba", "hybrid"):
         inp, kv = x, None
         if kind == "hybrid":
-            t, kv = shared_block(shared, bp, x, e0, positions, cfg, impl, cache, cache_index)
+            t, kv = shared_block(shared, bp, x, e0, positions, cfg, cache, cache_index)
             inp = x + t
         h = rms_norm(inp, bp["ln1"], cfg.norm_eps)
         with jax.named_scope("mamba2.mixer"):
             if cache is not None:
                 out, new_state = mamba_decode_step(bp["mamba"], h, cache, cfg)
                 return x + out, aux, new_state if kv is None else {**new_state, **kv}
-            out, _ = mamba_block(bp["mamba"], h, cfg, impl=impl)
+            out, _ = mamba_block(bp["mamba"], h, cfg)
         return x + out, aux, None
 
     window = _kind_window(kind, cfg)
@@ -195,7 +191,6 @@ def apply_block(
         rope_theta=theta,
         cache=cache,
         cache_index=cache_index,
-        impl=impl,
     )
     x = x + attn_out
     h = rms_norm(x, bp["ln2"], cfg.norm_eps)
@@ -217,8 +212,6 @@ def _apply_run(
     cfg: ModelConfig,
     shared: Optional[Dict],
     e0: jnp.ndarray,
-    impl: str,
-    ep_axis: Optional[str],
     caches: Optional[Dict] = None,
     cache_index: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[Dict]]:
@@ -238,8 +231,6 @@ def _apply_run(
             cfg,
             shared=block,
             e0=e0,
-            impl=impl,
-            ep_axis=ep_axis,
             cache=cache_i,
             cache_index=cache_index,
         )
@@ -255,8 +246,6 @@ def forward(
     tokens: jnp.ndarray,  # [B, S_text]
     cfg: ModelConfig,
     prefix_embeds: Optional[jnp.ndarray] = None,  # [B, P, d] (vlm stub)
-    impl: str = "ref",
-    ep_axis: Optional[str] = None,
     remat: bool = False,
     last_only: bool = False,
     return_hidden: bool = False,
@@ -282,9 +271,7 @@ def forward(
     for first, period, _ in cfg.layer_runs():
         def body(carry, gp, first=first, period=period):
             x, aux = carry
-            x, a, _ = _apply_run(
-                x, gp, first, period, positions, cfg, shared, e0, impl, ep_axis
-            )
+            x, a, _ = _apply_run(x, gp, first, period, positions, cfg, shared, e0)
             return (constrain(x, "residual"), aux + a), None
 
         if remat:
@@ -308,8 +295,6 @@ def loss_fn(
     params: Dict,
     batch: Dict[str, jnp.ndarray],
     cfg: ModelConfig,
-    impl: str = "ref",
-    ep_axis: Optional[str] = None,
     remat: bool = True,
     ce_chunk: int = 512,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
@@ -318,14 +303,12 @@ def loss_fn(
 
     The CE is computed CHUNKED over the sequence (never materializing
     [B, S, V] logits) — with V up to 262k this is the difference between
-    fitting HBM and not (EXPERIMENTS.md §Perf)."""
+    fitting HBM and not."""
     hidden, aux = forward(
         params,
         batch["tokens"],
         cfg,
         prefix_embeds=batch.get("prefix_embeds"),
-        impl=impl,
-        ep_axis=ep_axis,
         remat=remat,
         return_hidden=True,
     )
@@ -411,8 +394,6 @@ def decode_step(
     tokens: jnp.ndarray,  # [B, 1]
     pos_index: jnp.ndarray,  # scalar int32: write position in the cache
     cfg: ModelConfig,
-    impl: str = "ref",
-    ep_axis: Optional[str] = None,
 ) -> Tuple[jnp.ndarray, Dict]:
     """One-token decode against a KV/SSM cache; returns (logits [B,1,V],
     new cache)."""
@@ -437,8 +418,6 @@ def decode_step(
                 cfg,
                 shared,
                 e0,
-                impl,
-                ep_axis,
                 caches=gc,
                 cache_index=pos_index,
             )

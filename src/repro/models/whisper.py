@@ -8,7 +8,7 @@ implemented fully, with both stacks scanned over layers.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,8 +70,7 @@ def init_encdec(rng, cfg: ModelConfig, ep: int = 1) -> Dict:
 
 # ---------------------------------------------------------------- encoder
 def encode(
-    params: Dict, frames: jnp.ndarray, cfg: ModelConfig, remat: bool = False,
-    impl: str = "ref",
+    params: Dict, frames: jnp.ndarray, cfg: ModelConfig, remat: bool = False
 ) -> jnp.ndarray:
     """frames: [B, F, d_model] (stub frontend output) → enc states."""
     from ..distributed.context import constrain
@@ -83,7 +82,7 @@ def encode(
     def body(x, lp):
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         out, _ = attention_block(
-            lp["attn"], h, positions, cfg, causal=False, window=None, impl=impl
+            lp["attn"], h, positions, cfg, causal=False, window=None
         )
         x = x + out
         h = rms_norm(x, lp["ln2"], cfg.norm_eps)
@@ -103,7 +102,6 @@ def forward(
     frames: jnp.ndarray,  # [B, F, d_model]
     tokens: jnp.ndarray,  # [B, S]
     cfg: ModelConfig,
-    impl: str = "ref",
     remat: bool = False,
     last_only: bool = False,
     return_hidden: bool = False,
@@ -111,20 +109,18 @@ def forward(
     """Teacher-forced enc-dec forward → (logits, aux=0)."""
     from ..distributed.context import constrain
 
-    enc_out = encode(params, frames, cfg, remat=remat, impl=impl)
+    enc_out = encode(params, frames, cfg, remat=remat)
     x = constrain(embed(tokens, params["embed"], cfg), "residual")
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
 
     def body(x, lp):
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        out, _ = attention_block(
-            lp["self_attn"], h, positions, cfg, causal=True, impl=impl
-        )
+        out, _ = attention_block(lp["self_attn"], h, positions, cfg, causal=True)
         x = x + out
         h = rms_norm(x, lp["ln_x"], cfg.norm_eps)
         kv = precompute_cross_kv(lp["cross_attn"], enc_out, cfg)
-        x = x + cross_attention_block(lp["cross_attn"], h, kv, cfg, impl=impl)
+        x = x + cross_attention_block(lp["cross_attn"], h, kv, cfg)
         h = rms_norm(x, lp["ln2"], cfg.norm_eps)
         return constrain(x + mlp(h, lp["mlp"], cfg), "residual"), None
 
@@ -146,13 +142,10 @@ def loss_fn(
     params: Dict,
     batch: Dict[str, jnp.ndarray],
     cfg: ModelConfig,
-    impl: str = "ref",
-    ep_axis: Optional[str] = None,
     remat: bool = True,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     hidden, _ = forward(
-        params, batch["frames"], batch["tokens"], cfg, impl=impl, remat=remat,
-        return_hidden=True,
+        params, batch["frames"], batch["tokens"], cfg, remat=remat, return_hidden=True
     )
     from .layers import chunked_cross_entropy
 
@@ -199,8 +192,6 @@ def decode_step(
     tokens: jnp.ndarray,  # [B, 1]
     pos_index: jnp.ndarray,
     cfg: ModelConfig,
-    impl: str = "ref",
-    ep_axis: Optional[str] = None,
 ) -> Tuple[jnp.ndarray, Dict]:
     x = embed(tokens, params["embed"], cfg)
     b = x.shape[0]
@@ -219,7 +210,6 @@ def decode_step(
             causal=True,
             cache={"k": kc, "v": vc},
             cache_index=pos_index,
-            impl=impl,
         )
         x = x + out
         h = rms_norm(x, lp["ln_x"], cfg.norm_eps)

@@ -9,8 +9,8 @@ Design for scale:
     masters (GSPMD renders this as reduce-scatter(grads) → local update →
     all-gather(params) — the ZeRO-1 schedule);
   * everything is a pure function over pytrees: ``init`` is
-    eval_shape-safe, so dry-runs get the full optimizer memory picture with
-    zero allocation.
+    eval_shape-safe, so the optimizer state's shapes and bytes are known
+    with zero allocation.
 """
 
 from __future__ import annotations

@@ -2,9 +2,9 @@
 SSM-state) cache.
 
 ``serve_step`` — one new token for every sequence in the batch against a
-cache of ``max_len`` — is the function the decode_* and long_500k dry-run
-cells lower (assignment: "``decode_*`` / ``long_*`` lower ``serve_step``,
-NOT ``train_step``").
+cache of ``max_len`` — is the decode program: ``jit_serve_step`` compiles
+it with the KV cache donated, and every serving replica (``DecodeEngine``)
+and the chip benchmark's cells run it.
 """
 
 from __future__ import annotations

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import SMOKE_SHAPE, get_config, list_archs
+from repro.configs import SMOKE_SHAPE, get_config, list_archs, reduced
 from repro.models import build_model, make_fake_batch
 
 ARCHS = list_archs()
@@ -19,6 +19,26 @@ def rng():
 def _build(name):
     cfg = get_config(name + "-smoke")
     return cfg, build_model(cfg)
+
+
+@pytest.mark.parametrize("name", ["no-such-arch", "no-such-arch-smoke"])
+def test_get_config_unknown_name_names_the_known(name):
+    with pytest.raises(KeyError) as err:
+        get_config(name)
+    message = str(err.value)
+    assert "no-such-arch" in message
+    assert all(repr(arch) in message for arch in ARCHS)
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_smoke_name_is_reduced_config_of_same_kinds(arch):
+    cfg, small = get_config(arch), get_config(arch + "-smoke")
+    assert small == reduced(cfg)
+    assert small.name == arch + "-smoke"
+    assert small.family == cfg.family
+    assert small.pattern == cfg.pattern
+    assert set(small.layer_kinds()) == set(cfg.layer_kinds())
+    assert small.d_model < cfg.d_model and small.vocab_size < cfg.vocab_size
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -1,5 +1,7 @@
 """Per-kernel validation: hypothesis sweeps over shapes/dtypes, allclose
-against the pure-jnp ref oracles (kernels run in interpret mode on CPU)."""
+against the pure-jnp ref oracles, and the attention kernels against the
+model's own attention functions at its head widths (kernels run in
+interpret mode on CPU)."""
 
 import jax
 import jax.numpy as jnp
@@ -7,6 +9,7 @@ import pytest
 pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st
 
+from repro.configs import get_config
 from repro.kernels.cases import TOL_BF16, TOL_F32
 from repro.kernels.cases import max_normalized_error as _maxerr
 from repro.kernels.decode_attention import decode_attention
@@ -17,6 +20,12 @@ from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.rmsnorm.ref import rmsnorm_ref, rmsnorm_residual_ref
 from repro.kernels.ssd_scan import ssd
 from repro.kernels.ssd_scan.ref import ssd_chunked
+from repro.models.attention import (
+    decode_gqa_attention,
+    gqa_attention,
+    seen_slots,
+    write_token,
+)
 
 
 def _tol(dtype):
@@ -113,6 +122,74 @@ def test_decode_matches_flash_at_last_position():
     pos = jnp.full((b,), s - 1, jnp.int32)
     dec = decode_attention(q[:, -1:], k, v, pos, block_k=128)
     assert _maxerr(pre[:, -1:], dec) < 2e-5
+
+
+# ----------------------------------------- attention kernels vs the model
+#: (config, scale of the scores the model uses): danube's 32 q / 8 kv heads
+#: of 80 at head_dim**-0.5, zamba2's shared block's 32 / 32 of 224 at
+#: (head_dim/2)**-0.5
+MODEL_HEADS = {
+    "danube": ("h2o-danube-1.8b", lambda d: d**-0.5),
+    "zamba2": ("zamba2-7b", lambda d: (d / 2) ** -0.5),
+}
+MODEL_CASES = [
+    pytest.param(model, window, id=f"{model}-{'causal' if window is None else f'window{window}'}")
+    for model in MODEL_HEADS
+    for window in (None, 16)
+]
+
+
+def _heads(model):
+    name, scale = MODEL_HEADS[model]
+    cfg = get_config(name)
+    d = cfg.head_dim_
+    # the kernels scale by head_dim**-0.5: a query scaled by the ratio
+    # gives them the model's scale
+    return cfg.n_heads, cfg.n_kv_heads, d, scale(d), scale(d) / d**-0.5
+
+
+@pytest.mark.parametrize("model, window", MODEL_CASES)
+def test_flash_attention_matches_model(model, window):
+    hq, hkv, d, scale, q_ratio = _heads(model)
+    b, s = 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(hq + d), 3)
+    q = jax.random.normal(ks[0], (b, s, hq, d))
+    k = jax.random.normal(ks[1], (b, s, hkv, d))
+    v = jax.random.normal(ks[2], (b, s, hkv, d))
+    pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+    ref = gqa_attention(q, k, v, pos, pos, causal=True, window=window, scale=scale)
+    out = flash_attention(
+        q * q_ratio, k, v, causal=True, window=window, block_q=64, block_k=64
+    )
+    assert _maxerr(out, ref) < TOL_F32
+
+
+@pytest.mark.parametrize("model, window", MODEL_CASES)
+def test_decode_attention_matches_model(model, window):
+    """The kernel reads the cache with the token already written at its
+    slot; the model scores the cache's ``seen_slots`` and the token's own
+    key apart."""
+    hq, hkv, d, scale, q_ratio = _heads(model)
+    b, slots, index = 2, 64, 40
+    ks = jax.random.split(jax.random.PRNGKey(hq + d), 5)
+    q = jax.random.normal(ks[0], (b, 1, hq, d))
+    ck = jax.random.normal(ks[1], (b, slots, hkv, d))
+    cv = jax.random.normal(ks[2], (b, slots, hkv, d))
+    k = jax.random.normal(ks[3], (b, 1, hkv, d))
+    v = jax.random.normal(ks[4], (b, 1, hkv, d))
+    cache_index = jnp.int32(index)
+    positions = jnp.full((b, 1), index, jnp.int32)
+    seen = seen_slots(positions, cache_index, slots, window)
+    ref = decode_gqa_attention(q, ck, cv, k, v, seen, scale=scale)
+    out = decode_attention(
+        q * q_ratio,
+        write_token(ck, k, cache_index, axis=1),
+        write_token(cv, v, cache_index, axis=1),
+        positions[:, 0],
+        window=window,
+        block_k=64,
+    )
+    assert _maxerr(out, ref) < TOL_F32
 
 
 # -------------------------------------------------------------- ssd scan

@@ -1,16 +1,11 @@
-"""MoE routing/dispatch invariants + blocked attention + chunked CE."""
+"""MoE routing/dispatch invariants + chunked CE."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-pytest.importorskip("hypothesis", reason="property tests need hypothesis")
-from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
 from repro.configs.base import MoEConfig, reduced
-from repro.models.attention import gqa_attention
-from repro.models.blocked_attention import blocked_attention
 from repro.models.layers import chunked_cross_entropy, softmax_cross_entropy, unembed
 from repro.models.moe import _capacity, init_moe, moe_mlp_local
 
@@ -76,52 +71,6 @@ def test_capacity_rounding():
     cfg = moe_cfg(n_experts=8, top_k=2, cap=1.25)
     c = _capacity(1024, cfg)
     assert c % 8 == 0 and c >= 1024 * 2 * 1.25 / 8
-
-
-# ------------------------------------------------------- blocked attention
-@settings(max_examples=10, deadline=None)
-@given(
-    sq=st.sampled_from([48, 96, 130]),
-    hkv=st.sampled_from([1, 2]),
-    g=st.sampled_from([1, 3]),
-    causal=st.booleans(),
-    window=st.sampled_from([None, 24]),
-    bk=st.sampled_from([32, 64]),
-)
-def test_prop_blocked_attention_matches_ref(sq, hkv, g, causal, window, bk):
-    rng = jax.random.PRNGKey(sq * 7 + bk)
-    b, d = 2, 32
-    hq = hkv * g
-    ks = jax.random.split(rng, 3)
-    q = jax.random.normal(ks[0], (b, sq, hq, d))
-    k = jax.random.normal(ks[1], (b, sq, hkv, d))
-    v = jax.random.normal(ks[2], (b, sq, hkv, d))
-    pos = jnp.broadcast_to(jnp.arange(sq)[None], (b, sq))
-    out = blocked_attention(q, k, v, pos, pos, causal, window, bk, False)
-    ref = gqa_attention(q, k, v, pos, pos, causal=causal, window=window)
-    assert float(jnp.abs(out - ref).max()) < 1e-4
-
-
-def test_blocked_attention_grad_matches_ref():
-    rng = jax.random.PRNGKey(3)
-    b, s, hq, hkv, d = 1, 64, 4, 2, 16
-    ks = jax.random.split(rng, 3)
-    q, k, v = (jax.random.normal(ks[i], (b, s, hq if i == 0 else hkv, d)) for i in range(3))
-    pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-
-    def f(fn):
-        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
-
-    g_blk = jax.grad(
-        f(lambda q, k, v: blocked_attention(q, k, v, pos, pos, True, None, 32, False)),
-        argnums=(0, 1, 2),
-    )(q, k, v)
-    g_ref = jax.grad(
-        f(lambda q, k, v: gqa_attention(q, k, v, pos, pos, causal=True)),
-        argnums=(0, 1, 2),
-    )(q, k, v)
-    for a, r in zip(g_blk, g_ref):
-        assert float(jnp.abs(a - r).max()) < 2e-3
 
 
 # ----------------------------------------------------------- chunked CE

@@ -77,8 +77,8 @@ SCRIPT = textwrap.dedent(
     out["whisper_q"] = str(specsw["decoder"]["self_attn"]["q"]["w"])
 
     # batch + cache specs
-    from repro.configs import get_shape
-    bs = batch_specs(api.batch_spec(get_shape("train_4k")), ctx)
+    from repro.configs import ShapeConfig
+    bs = batch_specs(api.batch_spec(ShapeConfig("train", 4096, 256, "train")), ctx)
     out["tokens"] = str(bs["tokens"])
     cache_shapes = jax.eval_shape(lambda: api.init_cache(128, 4096))
     cs = cache_specs(cache_shapes, cfg, ctx)
